@@ -39,7 +39,7 @@ def _tiny() -> bool:
     return os.environ.get("BENCH_JOIN_TINY", "") not in ("", "0")
 
 
-def _corpus(n_rows: int, n_entities: int, dim: int, noise: float, seed: int):
+def corpus(n_rows: int, n_entities: int, dim: int, noise: float, seed: int):
     """Entity-clustered normalized embeddings: within-entity cosine is high
     (real candidate structure at tau), cross-entity is near zero."""
     import jax.numpy as jnp
@@ -74,8 +74,8 @@ def _bench_blocked_path(out: list, payload: dict):
                              tiles_per_call=256, recall_floor=RECALL_FLOOR)
         capacity = 1 << 22
         sample = 1024
-    ids_a, a, ids_b, b = _corpus(n_rows, n_entities, dim=16, noise=0.12,
-                                 seed=0)
+    ids_a, a, ids_b, b = corpus(n_rows, n_entities, dim=16, noise=0.12,
+                                seed=0)
     # compile the kernel on a sliver so the timed run measures execution
     blocked_candidates(a[:2 * cfg.bn], b[:2 * cfg.bm], tau, cfg,
                        capacity=256, normalize=False)

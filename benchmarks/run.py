@@ -66,6 +66,10 @@ def _trajectory(payloads: dict) -> dict:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     from . import (bench_blocking, bench_join_service, bench_plan,
                    bench_streaming, boruvka_parity, fig11_clusters,
                    fig12_transitive, fig13_orders, fig14_parallel,

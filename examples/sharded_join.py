@@ -50,10 +50,11 @@ for rid, tag in ((r1, "tau=0.55 perfect"), (r2, "tau=0.70 noisy  ")):
 
 # -- blocked machine phase (DESIGN.md §12) ----------------------------------
 # LSH buckets in front of the scorer: only colliding buckets reach the
-# fused similarity/threshold/compaction kernel, so the dense 300x280 grid
-# is never scored (or materialized).  The config is sized for a recall
-# floor at the threshold boundary; surviving pairs score bitwise-equal to
-# the dense path, so the join result is the same minus blocker misses.
+# fused similarity/threshold kernel, so the dense 300x280 grid is never
+# scored.  The config is sized for a recall floor at the threshold
+# boundary; surviving pairs score as the dense path scores them (to a few
+# ulps: the tile shape sets the summation order), so the join result is
+# the same minus blocker misses.
 from repro.kernels.pair_scores.blocking import BlockingConfig
 
 cfg = BlockingConfig.for_recall(0.95, threshold=0.7, n_bits=5)

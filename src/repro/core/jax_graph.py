@@ -763,9 +763,10 @@ def _apply_fast_flagged_impl(state: SessionState, updates: jax.Array,
         cmask, has_conflict
 
 
-def _deduce_from_impl(state: SessionState, ded: jax.Array) -> SessionState:
-    """Fold a precomputed per-pair deduction sweep ``ded`` into the state —
-    the shared tail of :func:`_deduce_impl` and the fused-kernel deduce.
+def _deduce_impl(state: SessionState) -> SessionState:
+    """One deduction sweep over the maintained roots + neg-key index.  Pairs
+    still in flight (``published``) are skipped — their crowd answers are the
+    ones that will label them (§5.2 stream semantics).
 
     Deduction needs no structural maintenance beyond duplicate neg keys: a
     deduced-POS pair has equal roots by construction (no union can occur, so
@@ -773,6 +774,8 @@ def _deduce_from_impl(state: SessionState, ded: jax.Array) -> SessionState:
     adjacent clusters — its key is merged in as a duplicate, which is what a
     from-scratch rebuild would also contain, keeping the state bit-identical."""
     n = state.n_objects
+    ded = _deduce_lookup_impl(state.roots, state.neg_keys, state.u, state.v,
+                              n)
     new = (ded != UNKNOWN) & (state.labels == UNKNOWN) & ~state.published
     labels = jnp.where(new, ded, state.labels)
     neg_new = new & (ded == NEG)
@@ -787,51 +790,6 @@ def _deduce_from_impl(state: SessionState, ded: jax.Array) -> SessionState:
         lambda nk: _merge_sorted_impl(nk, jnp.sort(fresh)),
         lambda nk: nk, state.neg_keys)
     return dataclasses.replace(state, labels=labels, neg_keys=negk)
-
-
-def _deduce_impl(state: SessionState) -> SessionState:
-    """One deduction sweep over the maintained roots + neg-key index.  Pairs
-    still in flight (``published``) are skipped — their crowd answers are the
-    ones that will label them (§5.2 stream semantics)."""
-    ded = _deduce_lookup_impl(state.roots, state.neg_keys, state.u, state.v,
-                              state.n_objects)
-    return _deduce_from_impl(state, ded)
-
-
-# ---------------------------------------------------------------------------
-# Fused union–deduce routing (DESIGN.md §13): on TPU the screen's optimistic
-# union + self-key check and the deduce sweep's lookup go through the single
-# Pallas kernel in ``kernels/union_deduce``; elsewhere the XLA primitives
-# below are already fused by jit and bit-identical to the kernel's ref path.
-# ---------------------------------------------------------------------------
-def _screen_fused(state: SessionState, updates: jax.Array):
-    """Drop-in for :func:`_screen_impl` that routes the optimistic union and
-    the old-key self-key scan through the fused kernel on TPU backends."""
-    if jax.default_backend() != "tpu":
-        return _screen_impl(state, updates)
-    from repro.kernels.union_deduce.ops import fused_union_deduce
-    n = state.n_objects
-    new = (updates != UNKNOWN) & (state.labels == UNKNOWN)
-    pos_new = new & (updates == POS)
-    neg_new = new & (updates == NEG)
-    roots_opt, _, old_conflict = fused_union_deduce(
-        state.roots, state.u, state.v, pos_new, state.neg_keys, n)
-    fresh_self = neg_new & (roots_opt[state.u] == roots_opt[state.v])
-    has_conflict = old_conflict | jnp.any(fresh_self)
-    return new, pos_new, neg_new, roots_opt, has_conflict
-
-
-def _deduce_fused(state: SessionState) -> SessionState:
-    """Drop-in for :func:`_deduce_impl` via the fused kernel on TPU: with an
-    all-False union mask the kernel's no-op union on the compressed forest
-    and identity re-key reduce it to the plain deduce lookup."""
-    if jax.default_backend() != "tpu":
-        return _deduce_impl(state)
-    from repro.kernels.union_deduce.ops import fused_union_deduce
-    _, ded, _ = fused_union_deduce(
-        state.roots, state.u, state.v, jnp.zeros(state.u.shape, bool),
-        state.neg_keys, state.n_objects)
-    return _deduce_from_impl(state, ded)
 
 
 def _fold_impl(state: SessionState, updates: jax.Array,
@@ -1011,14 +969,14 @@ def _run_rounds_impl(state: SessionState, answers: jax.Array,
         st = _refresh_masked_impl(st0, prior, adaptive)
         frontier = _frontier_impl(st)
         updates = jnp.where(frontier, answers, UNKNOWN)
-        new, pos_new, neg_new, roots_opt, has_conflict = _screen_fused(
+        new, pos_new, neg_new, roots_opt, has_conflict = _screen_impl(
             st, updates)
         labels, roots, negk, cmask = _apply_fast(st, updates, new, pos_new,
                                                  neg_new, roots_opt)
         folded = _finish_apply(st, labels, roots, negk, cmask, new,
                                count_round=True,
                                keep_conflicts_published=False)
-        folded = _deduce_fused(folded)
+        folded = _deduce_impl(folded)
         empty = ~jnp.any(frontier)
         conflict = has_conflict & ~done0
         advanced = ~done0 & ~conflict & ~empty

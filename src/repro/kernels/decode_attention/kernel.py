@@ -16,10 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed from TPUCompilerParams after jax 0.4.x
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 DEFAULT_BS = 512
 NEG_INF = -1e30
 
@@ -94,7 +90,7 @@ def decode_attention(q, k_cache, v_cache, length, bs: int = DEFAULT_BS,
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, qg, kg, vg)

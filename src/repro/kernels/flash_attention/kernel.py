@@ -21,10 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed from TPUCompilerParams after jax 0.4.x
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
 NEG_INF = -1e30
@@ -105,7 +101,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

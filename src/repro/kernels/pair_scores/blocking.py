@@ -8,8 +8,8 @@ LSH hashes every row into ``n_bits``-bit bucket codes across ``n_tables``
 independent tables, and only (a-row, b-row) pairs that collide in at least
 one table's bucket ever reach the kernel.  Colliding buckets are chunked
 into (bn x bm) tiles and streamed through ``pair_scores_compact``, which
-fuses similarity, threshold, and on-chip candidate compaction — the dense
-score matrix is never materialized in any memory space.
+fuses similarity and threshold in the kernel and compacts the candidates in
+the same jit — the dense score matrix exists one chunk of tiles at a time.
 
 Recall is a tunable contract, not luck: for unit vectors with cosine
 similarity ``s``, one hyperplane splits the pair with probability
@@ -40,6 +40,7 @@ import numpy as np
 
 from .kernel import pair_scores_compact
 from .ops import l2_normalize
+from .ref import similarity
 from .sharded import ShardedCandidates
 
 
@@ -271,16 +272,16 @@ def score_block_pairs(a, b, tiles_a: np.ndarray, tiles_b: np.ndarray,
         b_g = b_ext[jnp.asarray(gb)]
         ida = jnp.asarray(ta.reshape(-1, 1).astype(np.int32))
         idb = jnp.asarray(tb.reshape(-1, 1).astype(np.int32))
-        rows, cols, scores, n_tot = pair_scores_compact(
+        rows, cols, scores, n_tot = jax.device_get(pair_scores_compact(
             a_g, b_g, ida, idb, float(threshold), c_call, bn, bm,
-            interpret=interpret)
-        n_found = int(np.asarray(n_tot)[0, 0])
+            interpret=interpret))
+        n_found = int(n_tot)
         found_total += n_found
         keep = min(n_found, c_call, cap - kept_total)
         if keep > 0:
-            rows_acc.append(np.asarray(rows)[:keep, 0])
-            cols_acc.append(np.asarray(cols)[:keep, 0])
-            scores_acc.append(np.asarray(scores)[:keep, 0])
+            rows_acc.append(rows[:keep])
+            cols_acc.append(cols[:keep])
+            scores_acc.append(scores[:keep])
             kept_total += keep
     n_dropped = found_total - kept_total
     rows = (np.concatenate(rows_acc) if rows_acc
@@ -314,12 +315,13 @@ def blocked_candidates(a, b, threshold: float,
                        normalize: bool = True,
                        impl: str = "auto") -> BlockedCandidates:
     """Blocked machine phase: embeddings -> thresholded candidate pairs
-    without ever scoring (or materializing) the dense N x M grid.
+    without ever scoring the dense N x M grid.
 
     Hash both sides into LSH buckets, tile every bucket collision, and
-    stream the tiles through the fused similarity/threshold/compaction
-    kernel.  Pairs the blocker never buckets together are the recall cost
-    — size ``config`` with :meth:`BlockingConfig.for_recall` for a floor
+    stream the tiles through the fused similarity/threshold kernel and its
+    candidate compaction.  Pairs the blocker never buckets together are
+    the recall cost — size ``config`` with
+    :meth:`BlockingConfig.for_recall` for a floor
     at the threshold boundary, and measure with :func:`blocker_recall`."""
     config = config or BlockingConfig()
     if normalize:
@@ -355,7 +357,7 @@ def blocker_recall(cand, a, b, threshold: float,
     n_dense = 0
     n_hit = 0
     for c0 in range(0, M, col_chunk):
-        s = np.asarray(jnp.einsum("nd,md->nm", a_s, b[c0:c0 + col_chunk]))
+        s = np.asarray(similarity(a_s, b[c0:c0 + col_chunk]))
         ri, ci = np.nonzero(s >= threshold)
         keys = rows[ri] * np.int64(M) + (ci + c0)
         n_dense += len(keys)

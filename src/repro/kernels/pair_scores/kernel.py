@@ -1,6 +1,5 @@
 """Pallas TPU kernels: blocked all-pairs similarity + fused thresholding,
-and the fused similarity -> threshold -> on-chip compaction kernel behind
-the blocked candidate generator (DESIGN.md §12).
+over the dense grid and over a gathered tile list (DESIGN.md §12).
 
 The machine phase of the paper's pipeline scores N x M candidate pairs
 (496K for Cora; O(N^2) in general).  On TPU this is a classic MXU tiling
@@ -12,13 +11,13 @@ the kernel without a second pass over HBM.
 ``pair_scores`` keeps the dense layout (grid (N/bn, M/bm); the per-row
 count accumulator revisits its (bn, 1) block across the sequential minor
 grid axis).  ``pair_scores_compact`` is the scale-unlock variant: it walks
-a *list* of gathered bucket tiles (grid (T,)), and instead of emitting the
-(bn, bm) score block it compacts the above-threshold triples
-(row, col, score) into a fixed-capacity buffer **inside the kernel** — a
-cursor in SMEM scratch advances by each tile's candidate count, so the
-dense score matrix never exists in any memory space.  Overflow is a
-counted contract, not a crash: writes past ``capacity`` land in a
-one-tile slack region and the true total comes back for the caller's
+a *list* of gathered bucket tiles (grid (T,)), writes each tile's
+thresholded (bn, bm) scores to HBM, and one XLA compaction in the same jit
+packs the above-threshold triples (row, col, score) into a fixed-capacity
+buffer — a cumsum of the candidate mask gives every candidate its slot and
+a scatter writes it, so the dense score matrix exists one chunk of tiles at
+a time.  Overflow is a counted contract, not a crash: candidates past
+``capacity`` are dropped and the true total comes back for the caller's
 ``suggested_capacity`` arithmetic.
 """
 from __future__ import annotations
@@ -28,11 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-# renamed from TPUCompilerParams after jax 0.4.x
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+from .ref import similarity
 
 DEFAULT_BN = 256
 DEFAULT_BM = 256
@@ -41,10 +37,7 @@ DEFAULT_BM = 256
 def _make_kernel(threshold: float):
     def kernel(a_ref, b_ref, out_ref, cnt_ref):
         j = pl.program_id(1)
-        a = a_ref[...].astype(jnp.float32)          # (bn, D)
-        b = b_ref[...].astype(jnp.float32)          # (bm, D)
-        s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        s = similarity(a_ref[...], b_ref[...])          # (bn, bm)
         mask = s >= threshold
         out_ref[...] = jnp.where(mask, s, 0.0)
 
@@ -89,48 +82,10 @@ def pair_scores(a: jax.Array, b: jax.Array, threshold: float,
     )(a, b)
 
 
-def _make_compact_kernel(threshold: float, capacity: int, bn: int, bm: int):
-    W = bn * bm
-
-    def kernel(a_ref, b_ref, ida_ref, idb_ref,
-               rows_ref, cols_ref, scr_ref, n_ref, cur):
-        t = pl.program_id(0)
-
-        @pl.when(t == 0)
-        def _init():
-            cur[0] = 0
-            rows_ref[...] = jnp.full_like(rows_ref, -1)
-            cols_ref[...] = jnp.full_like(cols_ref, -1)
-            scr_ref[...] = jnp.zeros_like(scr_ref)
-
-        a = a_ref[...].astype(jnp.float32)              # (bn, D)
-        b = b_ref[...].astype(jnp.float32)              # (bm, D)
-        s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        ra = ida_ref[...][:, 0]                         # (bn,) global rows
-        cb = idb_ref[...][:, 0]                         # (bm,) global cols
-        # id -1 marks tile padding; padded gather rows are also zero vectors,
-        # so with threshold > 0 the mask is belt-and-braces
-        mask = (s >= threshold) & (ra[:, None] >= 0) & (cb[None, :] >= 0)
-        flat_m = mask.reshape(-1)
-        rows = jnp.broadcast_to(ra[:, None], (bn, bm)).reshape(-1)
-        cols = jnp.broadcast_to(cb[None, :], (bn, bm)).reshape(-1)
-        # stable candidate-first compaction of this tile
-        order = jnp.argsort(~flat_m, stable=True)
-        got = flat_m[order]
-        cnt = flat_m.sum().astype(jnp.int32)
-        # the cursor is where this tile's candidates start; each tile writes
-        # a full W-window (its invalid tail marked row -1) that the next
-        # tile overwrites from cursor + cnt, so [0, cursor) always holds
-        # exactly the compacted candidates.  Once the cursor passes
-        # ``capacity`` the clamp parks further writes in the slack tile.
-        base = jnp.minimum(cur[0], capacity)
-        rows_ref[pl.ds(base, W), :] = jnp.where(got, rows[order], -1)[:, None]
-        cols_ref[pl.ds(base, W), :] = jnp.where(got, cols[order], -1)[:, None]
-        scr_ref[pl.ds(base, W), :] = jnp.where(
-            got, s.reshape(-1)[order], 0.0)[:, None]
-        cur[0] = cur[0] + cnt
-        n_ref[0, 0] = cur[0]
+def _make_tile_kernel(threshold: float):
+    def kernel(a_ref, b_ref, out_ref):
+        s = similarity(a_ref[...], b_ref[...])          # (bn, bm)
+        out_ref[0] = jnp.where(s >= threshold, s, 0.0)
 
     return kernel
 
@@ -142,8 +97,8 @@ def pair_scores_compact(a_g: jax.Array, b_g: jax.Array,
                         ida: jax.Array, idb: jax.Array,
                         threshold: float, capacity: int,
                         bn: int, bm: int, interpret: bool = False):
-    """Fused similarity + threshold + on-chip candidate compaction over
-    gathered bucket tiles (DESIGN.md §12).
+    """Fused similarity + threshold over gathered bucket tiles, then
+    candidate compaction (DESIGN.md §12).
 
     a_g: (T*bn, D) / b_g: (T*bm, D) — tile-gathered L2-normalized
     embeddings (tile t's rows live at [t*bn, (t+1)*bn)); padding rows are
@@ -151,40 +106,45 @@ def pair_scores_compact(a_g: jax.Array, b_g: jax.Array,
     ids, -1 on padding.  Requires ``threshold > 0`` so zero padding can
     never score as a candidate.
 
-    Returns (rows (capacity + bn*bm, 1) i32, cols ditto, scores ditto f32,
-    n_total (1, 1) i32).  Entries [0, min(n_total, capacity)) are the
-    compacted candidates (tail marked -1); n_total is the true candidate
-    count, so ``n_total - capacity`` (when positive) is the overflow the
-    caller must surface.  The trailing bn*bm slack rows are scratch for
-    clamped overflow writes — never candidate data.
+    Returns (rows (capacity,) i32, cols ditto, scores ditto f32, n_total
+    () i32).  Entries [0, min(n_total, capacity)) are the candidates in
+    tile-list order, row-major within a tile; the tail is marked row/col
+    -1, score 0.  n_total is the true candidate count, so
+    ``n_total - capacity`` (when positive) is the overflow the caller must
+    surface.
     """
     T = a_g.shape[0] // bn
     D = a_g.shape[1]
-    W = bn * bm
     C = int(capacity)
-    return pl.pallas_call(
-        _make_compact_kernel(float(threshold), C, bn, bm),
+    tiles = pl.pallas_call(
+        _make_tile_kernel(float(threshold)),
         grid=(T,),
         in_specs=[
             pl.BlockSpec((bn, D), lambda t: (t, 0)),
             pl.BlockSpec((bm, D), lambda t: (t, 0)),
-            pl.BlockSpec((bn, 1), lambda t: (t, 0)),
-            pl.BlockSpec((bm, 1), lambda t: (t, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((C + W, 1), lambda t: (0, 0)),
-            pl.BlockSpec((C + W, 1), lambda t: (0, 0)),
-            pl.BlockSpec((C + W, 1), lambda t: (0, 0)),
-            pl.BlockSpec((1, 1), lambda t: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((C + W, 1), jnp.int32),
-            jax.ShapeDtypeStruct((C + W, 1), jnp.int32),
-            jax.ShapeDtypeStruct((C + W, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        out_specs=pl.BlockSpec((1, bn, bm), lambda t: (t, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((T, bn, bm), jnp.float32),
         interpret=interpret,
-    )(a_g, b_g, ida, idb)
+    )(a_g, b_g)
+    ida = ida.reshape(-1)
+    idb = idb.reshape(-1)
+    # id -1 marks tile padding; padded gather rows are also zero vectors,
+    # so with threshold > 0 the id mask is belt-and-braces
+    mask = ((tiles >= threshold)
+            & (ida.reshape(T, bn, 1) >= 0)
+            & (idb.reshape(T, 1, bm) >= 0)).reshape(-1)
+    # stable compaction: each candidate's slot is its rank in flat
+    # (tile, row, col) order; ranks past the capacity scatter out of range
+    # and are dropped, so the buffer keeps the first C candidates
+    slot = jnp.cumsum(mask, dtype=jnp.int32) - 1
+    n_total = slot[-1] + 1
+    src = jnp.zeros((C,), jnp.int32).at[
+        jnp.where(mask, slot, C)].set(
+            jnp.arange(mask.shape[0], dtype=jnp.int32), mode="drop")
+    got = jnp.arange(C) < n_total
+    t = src // (bn * bm)
+    rows = ida[src // bm]                      # = ida[t*bn + row in tile]
+    cols = idb[t * bm + src % bm]
+    return (jnp.where(got, rows, -1), jnp.where(got, cols, -1),
+            jnp.where(got, tiles.reshape(-1)[src], 0.0), n_total)

@@ -24,7 +24,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .kernel import pair_scores as _kernel_call
@@ -120,12 +119,12 @@ def _sharded_candidates_jit(a, b, *, threshold: float, capacity: int,
         )
         return out
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("data", None), P("model", None)),
         out_specs=(P("data", "model", None), P("data", "model", None),
                    P("data", "model", None), P("data", "model")),
-        check_rep=False,
+        check_vma=False,
     )
     # leading (1, 1) block axes inside the body become the global (dd, dm)
     # device grid outside — candidate buffers only, never the dense matrix
@@ -209,11 +208,11 @@ def sharded_pair_scores(
         cnt = jax.lax.psum(cnt, "model")
         return s, cnt
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("data", None), P("model", None)),
         out_specs=(P("data", "model"), P("data", None)),
-        check_rep=False,
+        check_vma=False,
     )
     s, cnt = jax.jit(fn)(a, b)
     return s[:N, :M], cnt[:N]
@@ -244,7 +243,7 @@ class StreamingCandidateIndex:
     the LSH bucket structure: arrivals hash into the *existing* buckets
     (signatures are deterministic in the seed, so an arrival's codes match
     the codes the corpus was bucketed with), and only tiles from buckets
-    the arrival touched reach the fused compaction kernel — the per-epoch
+    the arrival touched reach the fused tile kernel — the per-epoch
     work drops from the dense dN x M block to the colliding cells.
     """
 

@@ -95,8 +95,6 @@ def collective_bytes(hlo_text: str) -> dict:
 
 def cost_summary(compiled) -> dict:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per computation
-        ca = ca[0] if ca else {}
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0))}
 

@@ -10,14 +10,11 @@ import math
 
 import jax
 
-try:  # AxisType landed after jax 0.4.x; older pins fall back to defaults
-    from jax.sharding import AxisType
+from jax.sharding import AxisType
 
-    def _axis_types_kw(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:  # pragma: no cover - depends on installed jax
-    def _axis_types_kw(n_axes: int) -> dict:
-        return {}
+
+def _axis_types_kw(n_axes: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

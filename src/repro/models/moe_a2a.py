@@ -22,7 +22,6 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .config import ModelConfig
@@ -110,11 +109,11 @@ def moe_block_a2a(x: jax.Array, p: Dict, cfg: ModelConfig, mesh
     xt = x.reshape(T, d)
     batch_spec = P(data_axes + ("model",) if len(data_axes) > 1
                    else (data_axes[0], "model"))
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(batch_spec, P(), P("model"), P("model"), P("model")),
         out_specs=(batch_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     y, aux = fn(xt, p["router"], p["wi_gate"], p["wi_up"], p["wo"])
     return y.reshape(B, S, d), aux

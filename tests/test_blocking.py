@@ -5,8 +5,10 @@ The contract under test, on corpora small enough to score densely:
   - blocked candidates are a *subset* of dense candidates (blocking can
     only miss, never invent);
   - recall >= the configured floor;
-  - every surviving pair scores **bitwise-identically** to the dense path
-    (same f32 dot over the same normalized rows — no tolerance);
+  - every surviving pair scores within ``BAND`` of the dense oracle (same
+    f32 dot over the same normalized rows; only the tile shape's summation
+    order differs), and a blocked pair the oracle lacks scores within
+    ``BAND`` of the threshold;
   - the same three properties hold through StreamingCandidateIndex epochs,
     whose union must equal one batch blocked call exactly.
 
@@ -27,11 +29,13 @@ from repro.kernels.pair_scores.blocking import (BlockingConfig,
                                                 expected_recall,
                                                 score_block_pairs, signatures)
 from repro.kernels.pair_scores.ops import l2_normalize
-from repro.kernels.pair_scores.ref import candidates_ref
+from repro.kernels.pair_scores.ref import candidate_diff, candidates_ref
 from repro.kernels.pair_scores.sharded import StreamingCandidateIndex
 from repro.launch.mesh import make_host_mesh
 
 TAU = 0.85
+# score agreement with the oracle: a few f32 ulps near 1.0
+BAND = 1e-6
 # small tiles so tiny corpora still exercise multi-tile buckets, and one
 # jit entry serves the whole module
 CFG_KW = dict(n_bits=5, bn=16, bm=16, tiles_per_call=32)
@@ -40,7 +44,7 @@ CFG_KW = dict(n_bits=5, bn=16, bm=16, tiles_per_call=32)
 def _corpus(seed, n_a=40, n_b=36, n_entities=12, dim=16, noise=0.15):
     """Entity-clustered embeddings (same shape as the conftest factory) —
     real candidate structure at cosine thresholds, normalized up front so
-    score comparisons can be bitwise."""
+    both paths score the same rows."""
     rng = np.random.default_rng(seed)
     cents = rng.normal(size=(n_entities, dim))
     mk = lambda n: (cents[rng.integers(0, n_entities, n)]
@@ -59,15 +63,13 @@ def _assert_parity(cand, a, b, tau, floor):
     rr, rc, rs = candidates_ref(jnp.asarray(a), jnp.asarray(b), tau)
     dense = _pair_set(rr, rc)
     blocked = _pair_set(cand.rows, cand.cols)
-    assert blocked <= dense, "blocking invented candidates"
+    dmax, extra, _ = candidate_diff((cand.rows, cand.cols, cand.scores),
+                                    (rr, rc, rs))
+    assert (extra < tau + BAND).all(), "blocking invented candidates"
     recall, n_dense = blocker_recall(cand, a, b, tau)
     assert n_dense == len(dense)
     assert recall >= floor, (recall, floor)
-    ref_score = {(r, c): s for r, c, s in
-                 zip(rr.tolist(), rc.tolist(), rs.tolist())}
-    for r, c, s in zip(cand.rows.tolist(), cand.cols.tolist(),
-                       cand.scores.tolist()):
-        assert np.float32(s) == np.float32(ref_score[(r, c)]), (r, c)
+    assert dmax <= BAND
     return dense, blocked
 
 
@@ -106,7 +108,7 @@ def test_blocking_scores_fewer_cells_than_dense_at_floor_recall():
 
 def test_dense_tiling_equals_oracle_exactly():
     """The degenerate blocking (full-grid tiles) IS the dense path: same
-    set, bitwise scores, zero misses — isolates kernel-vs-oracle parity
+    set, zero misses — isolates kernel-vs-oracle parity
     from bucket-recall effects."""
     a, b = _corpus(3, n_a=37, n_b=51)
     cfg = BlockingConfig(**CFG_KW)
@@ -350,7 +352,7 @@ def test_append_embeddings_blocked_overflow_rolls_back_the_epoch():
 @settings(max_examples=15, deadline=None, derandomize=True)
 def test_property_blocked_parity(seed):
     """For any drawn corpus: blocked subset of dense, recall >= floor,
-    bitwise score parity.  The floor holds by for_recall's analytic
+    score parity within ``BAND``.  The floor holds by for_recall's analytic
     headroom at the boundary (capture at s=tau >= 1 - (1-floor)/20)."""
     a, b = _corpus(seed)
     cfg = BlockingConfig.for_recall(0.9, TAU, **CFG_KW)

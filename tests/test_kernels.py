@@ -10,9 +10,11 @@ from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.pair_scores.kernel import pair_scores_compact
 from repro.kernels.pair_scores.ops import l2_normalize, pair_scores
-from repro.kernels.pair_scores.ref import candidates_ref
+from repro.kernels.pair_scores.ref import candidate_diff, candidates_ref
 
 RNG = np.random.default_rng(0)
+# score agreement with the oracle: a few f32 ulps near 1.0
+BAND = 1e-6
 
 
 def _pallas_interpret_available() -> bool:
@@ -52,11 +54,10 @@ def _compact_dense(a, b, threshold, capacity, bn, bm, interpret=True):
         jnp.asarray(ta.reshape(-1, 1).astype(np.int32)),
         jnp.asarray(tb.reshape(-1, 1).astype(np.int32)),
         float(threshold), int(capacity), bn, bm, interpret=interpret)
-    rows = np.asarray(rows)[:capacity, 0]
+    rows = np.asarray(rows)
     keep = rows >= 0
-    return (rows[keep], np.asarray(cols)[:capacity, 0][keep],
-            np.asarray(scores)[:capacity, 0][keep],
-            int(np.asarray(n_tot)[0, 0]))
+    return (rows[keep], np.asarray(cols)[keep], np.asarray(scores)[keep],
+            int(n_tot))
 
 
 # ---------------------------------------------------------------------------
@@ -91,20 +92,24 @@ def test_pair_scores_counts_match_threshold_semantics():
                                        (33, 57, 16, 16), (7, 130, 8, 32)])
 def test_pair_scores_compact_matches_dense_oracle(N, M, bn, bm):
     """Full-grid tiling through the compact kernel must reproduce the dense
-    oracle's candidate set exactly — same (row, col) set, bitwise-equal f32
-    scores, true total count — including ragged tile edges."""
+    oracle's candidate set — scores within ``BAND`` (the tile shape sets the
+    f32 summation order), a pair in only one set only if it scores within
+    ``BAND`` of the threshold — with the true total count, in tile-list
+    order and row-major within a tile, including ragged tile edges."""
     a = l2_normalize(jnp.asarray(RNG.normal(size=(N, 16)), jnp.float32))
     b = l2_normalize(jnp.asarray(RNG.normal(size=(M, 16)), jnp.float32))
     tau = 0.3
     rows, cols, scores, n_tot = _compact_dense(a, b, tau, N * M, bn, bm)
     rr, rc, rs = candidates_ref(a, b, tau)
-    assert n_tot == len(rr)
-    assert set(zip(rows.tolist(), cols.tolist())) == \
-        set(zip(rr.tolist(), rc.tolist()))
-    ref_score = {(r, c): s for r, c, s in
-                 zip(rr.tolist(), rc.tolist(), rs.tolist())}
-    for r, c, s in zip(rows.tolist(), cols.tolist(), scores.tolist()):
-        assert np.float32(s) == np.float32(ref_score[(r, c)])
+    assert n_tot == len(rows)
+    dmax, extra, missing = candidate_diff((rows, cols, scores),
+                                          (rr, rc, rs))
+    assert dmax <= BAND
+    assert (extra < tau + BAND).all() and (missing < tau + BAND).all()
+    # dense_block_pairs lists tiles row-block-major: the output order is
+    # (row block, col block, row, col)
+    order = np.lexsort((cols, rows, cols // bm, rows // bn))
+    np.testing.assert_array_equal(order, np.arange(len(rows)))
 
 
 @needs_pallas_interpret
@@ -162,8 +167,8 @@ def test_pair_scores_compact_all_padding_tiles():
     ids_b = jnp.full((bm, 1), -1, jnp.int32)
     rows, cols, scores, n_tot = pair_scores_compact(
         a_g, b_g, ids_a, ids_b, 0.5, 16, bn, bm, interpret=True)
-    assert int(np.asarray(n_tot)[0, 0]) == 0
-    assert (np.asarray(rows)[:16] == -1).all()
+    assert int(n_tot) == 0
+    assert (np.asarray(rows) == -1).all()
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,7 @@
 """DESIGN.md §13 on-device round engine: ``session_run_rounds`` must be
 bit-identical to driving the legacy per-round entry points (refresh ->
 frontier -> fold) from the host with the same order-independent answers,
-batched must equal unbatched, donation must consume the input state, and the
-fused union–deduce Pallas kernel must match its XLA oracle in interpret
-mode."""
+batched must equal unbatched, and donation must consume the input state."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -350,83 +348,3 @@ def test_service_fused_rounds_parity(async_mode, order):
         assert a.round_sizes == b.round_sizes
         assert a.n_conflicts == b.n_conflicts
         assert a.n_spent_cents == b.n_spent_cents
-
-
-# ---------------------------------------------------------------------------
-# Fused union–deduce Pallas kernel vs XLA oracle (interpret tier)
-# ---------------------------------------------------------------------------
-def _union_deduce_interpret_available() -> bool:
-    if not hasattr(_union_deduce_interpret_available, "ok"):
-        from repro.kernels.union_deduce.ops import fused_union_deduce
-        try:
-            fused_union_deduce(
-                jnp.arange(4, dtype=jnp.int32),
-                jnp.zeros(2, jnp.int32), jnp.ones(2, jnp.int32),
-                jnp.zeros(2, bool),
-                jnp.full(2, jnp.iinfo(jnp.int32).max, jnp.int32), 4,
-                impl="interpret")
-            _union_deduce_interpret_available.ok = True
-        except Exception:
-            _union_deduce_interpret_available.ok = False
-    return _union_deduce_interpret_available.ok
-
-
-needs_interpret = pytest.mark.skipif(
-    not _union_deduce_interpret_available(),
-    reason="Pallas interpret-mode lowering unavailable on this jax install")
-
-
-def _check_union_deduce_kernel_matches_ref(seed):
-    from repro.core.jax_graph import neg_keys as make_neg_keys
-    from repro.kernels.union_deduce.ops import fused_union_deduce
-
-    rng = np.random.default_rng(seed)
-    n = int(rng.integers(3, 16))
-    p = int(rng.integers(2, 24))
-    u, v, truth = _random_session(rng, n, p)
-    pos_mask = jnp.asarray(truth == POS)
-    parent0 = jnp.arange(n, dtype=jnp.int32)
-    negk = np.asarray(make_neg_keys(
-        parent0, jnp.asarray(u), jnp.asarray(v), jnp.asarray(truth == NEG),
-        n))
-    outs = {impl: fused_union_deduce(
-        parent0, jnp.asarray(u), jnp.asarray(v), pos_mask,
-        jnp.asarray(negk), n, impl=impl)
-        for impl in ("ref", "interpret")}
-    for got, exp in zip(outs["interpret"], outs["ref"]):
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(exp),
-                                      err_msg=f"seed={seed}")
-
-
-@needs_interpret
-@settings(deadline=None, max_examples=10)
-@given(seed=st.integers(0, 2**31 - 1))
-def test_union_deduce_kernel_matches_ref(seed):
-    _check_union_deduce_kernel_matches_ref(seed)
-
-
-@needs_interpret
-@pytest.mark.parametrize("seed", [0, 1, 2, 3])
-def test_union_deduce_kernel_matches_ref_fixed(seed):
-    _check_union_deduce_kernel_matches_ref(seed)
-
-
-@needs_interpret
-def test_union_deduce_kernel_path_graph():
-    """Worst case for pointer jumping: one long path unioned in a single
-    call must fully compress within the kernel's fixed trip count."""
-    from repro.kernels.union_deduce.ops import fused_union_deduce
-
-    n = 64
-    u = np.arange(n - 1, dtype=np.int32)
-    v = np.arange(1, n, dtype=np.int32)
-    sentinel = jnp.iinfo(jnp.int32).max
-    args = (jnp.arange(n, dtype=jnp.int32), jnp.asarray(u), jnp.asarray(v),
-            jnp.ones(n - 1, bool),
-            jnp.full(n - 1, sentinel, jnp.int32), n)
-    roots_k, ded_k, conf_k = fused_union_deduce(*args, impl="interpret")
-    roots_r, ded_r, conf_r = fused_union_deduce(*args, impl="ref")
-    np.testing.assert_array_equal(np.asarray(roots_k), np.zeros(n, np.int32))
-    np.testing.assert_array_equal(np.asarray(roots_k), np.asarray(roots_r))
-    np.testing.assert_array_equal(np.asarray(ded_k), np.asarray(ded_r))
-    assert bool(conf_k) == bool(conf_r) == False  # noqa: E712
