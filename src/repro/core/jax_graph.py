@@ -84,30 +84,26 @@ import numpy as np
 # label encoding for the array engine (canonical home: cluster_graph.py,
 # which stays importable without jax)
 from .cluster_graph import NEG, POS, UNKNOWN
+from ..obs import engine_dispatches
 
 
 # ---------------------------------------------------------------------------
-# Dispatch accounting (DESIGN.md §8)
+# Program names in the device trace (DESIGN.md §8)
 # ---------------------------------------------------------------------------
-class DispatchCounter:
-    """Tally of host->device dispatches (compiled-function launches plus
-    host-array uploads) issued by the engine drivers, so benchmarks can show
-    the incremental session-state path doing less per round than the
-    from-scratch path (``benchmarks/bench_join_service.py``)."""
+def engine_jit(step: str, fn=None, **jit_kwargs):
+    """``jax.jit(fn, **jit_kwargs)`` under the program name
+    ``engine_<step>``, so the device trace shows it as
+    ``jit_engine_<step>`` whatever wraps ``fn``.  Without ``fn``, a
+    decorator."""
+    if fn is None:
+        return functools.partial(engine_jit, step, **jit_kwargs)
 
-    __slots__ = ("count",)
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
 
-    def __init__(self) -> None:
-        self.count = 0
-
-    def add(self, n: int = 1) -> None:
-        self.count += n
-
-    def reset(self) -> None:
-        self.count = 0
-
-
-engine_dispatches = DispatchCounter()
+    program.__name__ = program.__qualname__ = f"engine_{step}"
+    return jax.jit(program, **jit_kwargs)
 
 
 # ---------------------------------------------------------------------------
@@ -210,7 +206,7 @@ def _cc_impl(u, v, mask, n_objects: int) -> jax.Array:
                        n_objects)
 
 
-@functools.partial(jax.jit, static_argnames=("n_objects",))
+@engine_jit("connected_components", static_argnames=("n_objects",))
 def _connected_components_jit(u, v, mask, n_objects):
     return _cc_impl(u, v, mask, n_objects)
 
@@ -222,7 +218,7 @@ def connected_components(u: jax.Array, v: jax.Array, mask: jax.Array,
     return _connected_components_jit(u, v, mask, n_objects)
 
 
-@functools.partial(jax.jit, static_argnames=("n_objects",))
+@engine_jit("connected_components_batch", static_argnames=("n_objects",))
 def _connected_components_batch_jit(u, v, mask, n_objects):
     return jax.vmap(lambda uu, vv, mm: _cc_impl(uu, vv, mm, n_objects))(
         u, v, mask)
@@ -245,7 +241,7 @@ def _neg_keys_impl(roots, u, v, neg_mask, n_objects: int) -> jax.Array:
     return jnp.sort(keys)
 
 
-@functools.partial(jax.jit, static_argnames=("n_objects",))
+@engine_jit("neg_keys", static_argnames=("n_objects",))
 def _neg_keys_jit(roots, u, v, neg_mask, n_objects):
     return _neg_keys_impl(roots, u, v, neg_mask, n_objects)
 
@@ -317,7 +313,7 @@ def _deduce_lookup_impl(roots, sorted_neg, qu, qv, n_objects: int) -> jax.Array:
     return jnp.where(same, POS, jnp.where(neg, NEG, UNKNOWN)).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("n_objects",))
+@engine_jit("deduce_lookup", static_argnames=("n_objects",))
 def _deduce_batch_jit(roots, sorted_neg, qu, qv, n_objects):
     return _deduce_lookup_impl(roots, sorted_neg, qu, qv, n_objects)
 
@@ -439,7 +435,7 @@ def _state_from_labels_impl(u, v, labels, published, n_objects: int
                         n_objects=n_objects)
 
 
-@functools.partial(jax.jit, static_argnames=("n_objects",))
+@engine_jit("from_labels", static_argnames=("n_objects",))
 def _session_from_labels_jit(u, v, labels, published, n_objects):
     return _state_from_labels_impl(u, v, labels, published, n_objects)
 
@@ -498,14 +494,14 @@ def _grow_impl(state: SessionState, pair_capacity: int, object_capacity: int
     )
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("pair_capacity", "object_capacity"))
+@engine_jit("grow",
+            static_argnames=("pair_capacity", "object_capacity"))
 def _session_grow_jit(state, pair_capacity, object_capacity):
     return _grow_impl(state, pair_capacity, object_capacity)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("pair_capacity", "object_capacity"))
+@engine_jit("grow_batch",
+            static_argnames=("pair_capacity", "object_capacity"))
 def _session_grow_batch_jit(state, pair_capacity, object_capacity):
     return jax.vmap(functools.partial(
         _grow_impl, pair_capacity=pair_capacity,
@@ -567,8 +563,9 @@ def _append_pairs_impl(state: SessionState, new_u: jax.Array,
     )
 
 
-_session_append_pairs_jit = jax.jit(_append_pairs_impl)
-_session_append_pairs_batch_jit = jax.jit(jax.vmap(_append_pairs_impl))
+_session_append_pairs_jit = engine_jit("append_pairs", _append_pairs_impl)
+_session_append_pairs_batch_jit = engine_jit("append_pairs_batch",
+                                             jax.vmap(_append_pairs_impl))
 
 
 def session_append_pairs(state: SessionState, new_u, new_v, mask
@@ -999,8 +996,9 @@ def _run_rounds_impl(state: SessionState, answers: jax.Array,
 
 
 # jitted public entry points (counted host dispatches)
-_session_frontier_jit = jax.jit(_frontier_impl)
-_session_frontier_batch_jit = jax.jit(jax.vmap(_frontier_impl))
+_session_frontier_jit = engine_jit("frontier", _frontier_impl)
+_session_frontier_batch_jit = engine_jit("frontier_batch",
+                                         jax.vmap(_frontier_impl))
 
 
 def _apply_one(state, updates, keep_conflicts_published):
@@ -1008,17 +1006,18 @@ def _apply_one(state, updates, keep_conflicts_published):
                        keep_conflicts_published=keep_conflicts_published)
 
 
-def _batched(fn, donate: bool = False):
-    """vmap over (state, updates) with the static policy flag closed over.
-    ``donate`` hands the stacked state's buffers to XLA for in-place reuse
-    (DESIGN.md §13) — only safe for variants whose callers never touch the
-    input state again."""
+def _batched(step: str, fn, donate: bool = False):
+    """vmap over (state, updates) with the static policy flag closed over,
+    jitted as ``engine_<step>``.  ``donate`` hands the stacked state's
+    buffers to XLA for in-place reuse (DESIGN.md §13) — only safe for
+    variants whose callers never touch the input state again."""
     def call(state, updates, keep_conflicts_published):
         return jax.vmap(functools.partial(
             fn, keep_conflicts_published=keep_conflicts_published))(
                 state, updates)
-    return jax.jit(call, static_argnames=("keep_conflicts_published",),
-                   donate_argnums=(0,) if donate else ())
+    return engine_jit(step, call,
+                      static_argnames=("keep_conflicts_published",),
+                      donate_argnums=(0,) if donate else ())
 
 
 # Donation discipline (DESIGN.md §13): state-in/state-out transformations
@@ -1029,35 +1028,41 @@ def _batched(fn, donate: bool = False):
 # (cheap, callers often keep the old state), grow (shape-changing outputs
 # can't alias — XLA warns the donated buffers are unusable), and
 # session_from_labels (inputs are plain arrays the caller owns).
-_session_apply_jit = jax.jit(
-    _apply_one, static_argnames=("keep_conflicts_published",),
+_session_apply_jit = engine_jit(
+    "apply", _apply_one, static_argnames=("keep_conflicts_published",),
     donate_argnums=(0,))
 # exact batched variants: under vmap the screening cond lowers to a select
 # that executes BOTH branches, including the O(P^2) sequential replay — used
 # only as the fallback when a speculative fast fold's screen actually fired
-_session_apply_batch_jit = _batched(_apply_one, donate=True)
-_session_apply_fast_batch_jit = _batched(functools.partial(
-    _apply_fast_flagged_impl, count_round=True))
-_session_deduce_jit = jax.jit(_deduce_impl, donate_argnums=(0,))
-_session_deduce_batch_jit = jax.jit(jax.vmap(_deduce_impl),
-                                    donate_argnums=(0,))
-_session_fold_jit = jax.jit(
-    _fold_impl, static_argnames=("keep_conflicts_published",),
+_session_apply_batch_jit = _batched("apply_batch", _apply_one, donate=True)
+_session_apply_fast_batch_jit = _batched(
+    "apply_fast_batch",
+    functools.partial(_apply_fast_flagged_impl, count_round=True))
+_session_deduce_jit = engine_jit("deduce", _deduce_impl, donate_argnums=(0,))
+_session_deduce_batch_jit = engine_jit(
+    "deduce_batch", jax.vmap(_deduce_impl), donate_argnums=(0,))
+_session_fold_jit = engine_jit(
+    "fold", _fold_impl, static_argnames=("keep_conflicts_published",),
     donate_argnums=(0,))
-_session_fold_batch_jit = _batched(_fold_impl, donate=True)
-_session_fold_fast_batch_jit = _batched(_fold_fast_flagged_impl)
-_session_seed_jit = jax.jit(_seed_labels_impl, donate_argnums=(0,))
-_session_seed_batch_jit = jax.jit(jax.vmap(_seed_labels_impl),
-                                  donate_argnums=(0,))
-_session_seed_fast_batch_jit = jax.jit(
-    jax.vmap(_seed_labels_fast_flagged_impl))
-_session_mark_published_jit = jax.jit(_mark_published_impl)
-_session_mark_published_batch_jit = jax.jit(jax.vmap(_mark_published_impl))
-_session_trust_graph_jit = jax.jit(_trust_graph_impl, donate_argnums=(0,))
-_session_trust_graph_batch_jit = jax.jit(jax.vmap(_trust_graph_impl),
-                                         donate_argnums=(0,))
-_session_run_rounds_jit = jax.jit(
-    _run_rounds_impl, static_argnames=("max_rounds",), donate_argnums=(0,))
+_session_fold_batch_jit = _batched("fold_batch", _fold_impl, donate=True)
+_session_fold_fast_batch_jit = _batched("fold_fast_batch",
+                                        _fold_fast_flagged_impl)
+_session_seed_jit = engine_jit("seed", _seed_labels_impl, donate_argnums=(0,))
+_session_seed_batch_jit = engine_jit(
+    "seed_batch", jax.vmap(_seed_labels_impl), donate_argnums=(0,))
+_session_seed_fast_batch_jit = engine_jit(
+    "seed_fast_batch", jax.vmap(_seed_labels_fast_flagged_impl))
+_session_mark_published_jit = engine_jit("mark_published",
+                                         _mark_published_impl)
+_session_mark_published_batch_jit = engine_jit(
+    "mark_published_batch", jax.vmap(_mark_published_impl))
+_session_trust_graph_jit = engine_jit("trust_graph", _trust_graph_impl,
+                                      donate_argnums=(0,))
+_session_trust_graph_batch_jit = engine_jit(
+    "trust_graph_batch", jax.vmap(_trust_graph_impl), donate_argnums=(0,))
+_session_run_rounds_jit = engine_jit(
+    "run_rounds", _run_rounds_impl, static_argnames=("max_rounds",),
+    donate_argnums=(0,))
 
 
 def _run_rounds_batch(state, answers, prior, adaptive, rounds_allowed,
@@ -1067,8 +1072,9 @@ def _run_rounds_batch(state, answers, prior, adaptive, rounds_allowed,
             state, answers, prior, adaptive, rounds_allowed)
 
 
-_session_run_rounds_batch_jit = jax.jit(
-    _run_rounds_batch, static_argnames=("max_rounds",), donate_argnums=(0,))
+_session_run_rounds_batch_jit = engine_jit(
+    "run_rounds_batch", _run_rounds_batch, static_argnames=("max_rounds",),
+    donate_argnums=(0,))
 
 
 def session_frontier(state: SessionState) -> jax.Array:
@@ -1259,7 +1265,7 @@ def session_run_rounds_batch(state: SessionState, answers, max_rounds: int,
 # ---------------------------------------------------------------------------
 # Thin from-scratch wrappers (oracle parity tests; historical signatures)
 # ---------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnames=("n_objects",))
+@engine_jit("boruvka_frontier", static_argnames=("n_objects",))
 def _boruvka_frontier_jit(u, v, labels, published, n_objects):
     return _frontier_impl(
         _state_from_labels_impl(u, v, labels, published, n_objects))
@@ -1280,7 +1286,7 @@ def boruvka_frontier(u: jax.Array, v: jax.Array, labels: jax.Array,
     return _boruvka_frontier_jit(u, v, labels, published, n_objects)
 
 
-@functools.partial(jax.jit, static_argnames=("n_objects",))
+@engine_jit("boruvka_frontier_batch", static_argnames=("n_objects",))
 def _boruvka_frontier_batch_jit(u, v, labels, published, n_objects):
     def one(uu, vv, ll, pp):
         return _frontier_impl(
@@ -1300,7 +1306,7 @@ def boruvka_frontier_batch(u: jax.Array, v: jax.Array, labels: jax.Array,
     return _boruvka_frontier_batch_jit(u, v, labels, published, n_objects)
 
 
-@functools.partial(jax.jit, static_argnames=("n_objects",))
+@engine_jit("deduce_sessions", static_argnames=("n_objects",))
 def _deduce_sessions_jit(u, v, labels, n_objects):
     def one(uu, vv, ll):
         st = _state_from_labels_impl(uu, vv, ll,

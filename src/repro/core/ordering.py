@@ -64,7 +64,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .cluster_graph import ClusterGraph, UNKNOWN
-from .jax_graph import SessionState, _decompose_keys, engine_dispatches
+from .jax_graph import (SessionState, _decompose_keys, engine_dispatches,
+                        engine_jit)
 
 # Damping per unit of negative degree around the pair's clusters.  0.25 is a
 # power of two, so `1 + NEG_DAMP * k` is exact in f32 and the host/device
@@ -123,13 +124,15 @@ def _refresh_masked_impl(state: SessionState, prior: jax.Array,
     return dataclasses.replace(state, priority=prio)
 
 
-_session_gains_jit = jax.jit(_gains_impl)
-_session_gains_batch_jit = jax.jit(jax.vmap(_gains_impl))
+_session_gains_jit = engine_jit("gains", _gains_impl)
+_session_gains_batch_jit = engine_jit("gains_batch", jax.vmap(_gains_impl))
 # refresh is state-in/state-out: donate the state so the priority write is
 # in place and the untouched fields alias straight through (DESIGN.md §13)
-_session_refresh_jit = jax.jit(_refresh_impl, donate_argnums=(0,))
-_session_refresh_batch_jit = jax.jit(jax.vmap(_refresh_masked_impl),
-                                     donate_argnums=(0,))
+_session_refresh_jit = engine_jit("refresh", _refresh_impl,
+                                  donate_argnums=(0,))
+_session_refresh_batch_jit = engine_jit("refresh_batch",
+                                        jax.vmap(_refresh_masked_impl),
+                                        donate_argnums=(0,))
 
 
 def session_gains(state: SessionState, prior) -> jax.Array:

@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 from .kernel import pair_scores_compact
 from .ops import l2_normalize
 from .ref import similarity
@@ -126,13 +128,14 @@ def signatures(x, config: BlockingConfig) -> np.ndarray:
     (streaming arrivals vs the original corpus) land in the same buckets.
     Feed the *normalized* embeddings so batch and streaming paths see
     bit-identical projections."""
-    x = np.asarray(x, np.float32)
-    rng = np.random.default_rng(config.seed)
-    planes = rng.normal(
-        size=(config.n_tables, x.shape[1], config.n_bits)).astype(np.float32)
-    bits = np.einsum("nd,ldb->lnb", x, planes) >= 0.0
-    weights = (np.int64(1) << np.arange(config.n_bits, dtype=np.int64))
-    return bits @ weights
+    with obs.span("join.machine.signatures"):
+        x = np.asarray(obs.to_host(x), np.float32)
+        rng = np.random.default_rng(config.seed)
+        planes = rng.normal(size=(config.n_tables, x.shape[1],
+                                  config.n_bits)).astype(np.float32)
+        bits = np.einsum("nd,ldb->lnb", x, planes) >= 0.0
+        weights = (np.int64(1) << np.arange(config.n_bits, dtype=np.int64))
+        return bits @ weights
 
 
 def _pad_chunks(rows: np.ndarray, tile: int) -> np.ndarray:
@@ -156,31 +159,34 @@ def block_pairs(codes_a: np.ndarray, idx_a: np.ndarray,
     (tiles_a (T, bn), tiles_b (T, bm)) int64 global row indices, -1 padded
     — tile pair t means "score every (row of tiles_a[t]) x (row of
     tiles_b[t]) cell"."""
-    idx_a = np.asarray(idx_a, np.int64)
-    idx_b = np.asarray(idx_b, np.int64)
-    tiles_a: List[np.ndarray] = []
-    tiles_b: List[np.ndarray] = []
-    if len(idx_a) == 0 or len(idx_b) == 0:
-        return (np.zeros((0, bn), np.int64), np.zeros((0, bm), np.int64))
-    for table in range(codes_a.shape[0]):
-        ca = codes_a[table, idx_a]
-        cb = codes_b[table, idx_b]
-        oa = np.argsort(ca, kind="stable")
-        ob = np.argsort(cb, kind="stable")
-        ua, sa, na = np.unique(ca[oa], return_index=True, return_counts=True)
-        ub, sb, nb = np.unique(cb[ob], return_index=True, return_counts=True)
-        shared, ia, ib = np.intersect1d(ua, ub, assume_unique=True,
-                                        return_indices=True)
-        for k in range(len(shared)):
-            rows = idx_a[oa[sa[ia[k]]:sa[ia[k]] + na[ia[k]]]]
-            cols = idx_b[ob[sb[ib[k]]:sb[ib[k]] + nb[ib[k]]]]
-            ra = _pad_chunks(rows, bn)
-            rb = _pad_chunks(cols, bm)
-            tiles_a.append(ra[np.repeat(np.arange(len(ra)), len(rb))])
-            tiles_b.append(rb[np.tile(np.arange(len(rb)), len(ra))])
-    if not tiles_a:
-        return (np.zeros((0, bn), np.int64), np.zeros((0, bm), np.int64))
-    return np.concatenate(tiles_a), np.concatenate(tiles_b)
+    with obs.span("join.machine.block"):
+        idx_a = np.asarray(idx_a, np.int64)
+        idx_b = np.asarray(idx_b, np.int64)
+        tiles_a: List[np.ndarray] = []
+        tiles_b: List[np.ndarray] = []
+        if len(idx_a) == 0 or len(idx_b) == 0:
+            return (np.zeros((0, bn), np.int64), np.zeros((0, bm), np.int64))
+        for table in range(codes_a.shape[0]):
+            ca = codes_a[table, idx_a]
+            cb = codes_b[table, idx_b]
+            oa = np.argsort(ca, kind="stable")
+            ob = np.argsort(cb, kind="stable")
+            ua, sa, na = np.unique(ca[oa], return_index=True,
+                                   return_counts=True)
+            ub, sb, nb = np.unique(cb[ob], return_index=True,
+                                   return_counts=True)
+            shared, ia, ib = np.intersect1d(ua, ub, assume_unique=True,
+                                            return_indices=True)
+            for k in range(len(shared)):
+                rows = idx_a[oa[sa[ia[k]]:sa[ia[k]] + na[ia[k]]]]
+                cols = idx_b[ob[sb[ib[k]]:sb[ib[k]] + nb[ib[k]]]]
+                ra = _pad_chunks(rows, bn)
+                rb = _pad_chunks(cols, bm)
+                tiles_a.append(ra[np.repeat(np.arange(len(ra)), len(rb))])
+                tiles_b.append(rb[np.tile(np.arange(len(rb)), len(ra))])
+        if not tiles_a:
+            return (np.zeros((0, bn), np.int64), np.zeros((0, bm), np.int64))
+        return np.concatenate(tiles_a), np.concatenate(tiles_b)
 
 
 @dataclasses.dataclass
@@ -256,33 +262,34 @@ def score_block_pairs(a, b, tiles_a: np.ndarray, tiles_b: np.ndarray,
             [tiles_b, np.full((t_pad, bm), -1, np.int64)])
     c_call = min(cap, chunk * bn * bm)
     # padding rows gather the appended zero vector (index N / M)
-    a_ext = jnp.concatenate([a, jnp.zeros((1, D), a.dtype)])
-    b_ext = jnp.concatenate([b, jnp.zeros((1, D), b.dtype)])
-    rows_acc: List[np.ndarray] = []
-    cols_acc: List[np.ndarray] = []
-    scores_acc: List[np.ndarray] = []
-    kept_total = 0
-    found_total = 0
-    for t0 in range(0, tiles_a.shape[0], chunk):
-        ta = tiles_a[t0:t0 + chunk]
-        tb = tiles_b[t0:t0 + chunk]
-        ga = np.where(ta < 0, N, ta).reshape(-1)
-        gb = np.where(tb < 0, M, tb).reshape(-1)
-        a_g = a_ext[jnp.asarray(ga)]
-        b_g = b_ext[jnp.asarray(gb)]
-        ida = jnp.asarray(ta.reshape(-1, 1).astype(np.int32))
-        idb = jnp.asarray(tb.reshape(-1, 1).astype(np.int32))
-        rows, cols, scores, n_tot = jax.device_get(pair_scores_compact(
-            a_g, b_g, ida, idb, float(threshold), c_call, bn, bm,
-            interpret=interpret))
-        n_found = int(n_tot)
-        found_total += n_found
-        keep = min(n_found, c_call, cap - kept_total)
-        if keep > 0:
-            rows_acc.append(rows[:keep])
-            cols_acc.append(cols[:keep])
-            scores_acc.append(scores[:keep])
-            kept_total += keep
+    with obs.span("join.machine.score"):
+        a_ext = jnp.concatenate([a, jnp.zeros((1, D), a.dtype)])
+        b_ext = jnp.concatenate([b, jnp.zeros((1, D), b.dtype)])
+        rows_acc: List[np.ndarray] = []
+        cols_acc: List[np.ndarray] = []
+        scores_acc: List[np.ndarray] = []
+        kept_total = 0
+        found_total = 0
+        for t0 in range(0, tiles_a.shape[0], chunk):
+            ta = tiles_a[t0:t0 + chunk]
+            tb = tiles_b[t0:t0 + chunk]
+            ga = np.where(ta < 0, N, ta).reshape(-1)
+            gb = np.where(tb < 0, M, tb).reshape(-1)
+            a_g = a_ext[jnp.asarray(ga)]
+            b_g = b_ext[jnp.asarray(gb)]
+            ida = jnp.asarray(ta.reshape(-1, 1).astype(np.int32))
+            idb = jnp.asarray(tb.reshape(-1, 1).astype(np.int32))
+            rows, cols, scores, n_tot = obs.to_host(pair_scores_compact(
+                a_g, b_g, ida, idb, float(threshold), c_call, bn, bm,
+                interpret=interpret))
+            n_found = int(n_tot)
+            found_total += n_found
+            keep = min(n_found, c_call, cap - kept_total)
+            if keep > 0:
+                rows_acc.append(rows[:keep])
+                cols_acc.append(cols[:keep])
+                scores_acc.append(scores[:keep])
+                kept_total += keep
     n_dropped = found_total - kept_total
     rows = (np.concatenate(rows_acc) if rows_acc
             else np.zeros(0, np.int64)).astype(np.int64)
@@ -330,8 +337,8 @@ def blocked_candidates(a, b, threshold: float,
     codes_a = signatures(a, config)
     codes_b = signatures(b, config)
     tiles_a, tiles_b = block_pairs(
-        codes_a, np.arange(np.asarray(a).shape[0]),
-        codes_b, np.arange(np.asarray(b).shape[0]), config.bn, config.bm)
+        codes_a, np.arange(obs.to_host(a).shape[0]),
+        codes_b, np.arange(obs.to_host(b).shape[0]), config.bn, config.bm)
     return score_block_pairs(a, b, tiles_a, tiles_b, threshold, config,
                              capacity=capacity, impl=impl)
 
@@ -357,7 +364,7 @@ def blocker_recall(cand, a, b, threshold: float,
     n_dense = 0
     n_hit = 0
     for c0 in range(0, M, col_chunk):
-        s = np.asarray(similarity(a_s, b[c0:c0 + col_chunk]))
+        s = obs.to_host(similarity(a_s, b[c0:c0 + col_chunk]))
         ri, ci = np.nonzero(s >= threshold)
         keys = rows[ri] * np.int64(M) + (ci + c0)
         n_dense += len(keys)

@@ -79,6 +79,7 @@ def pair_scores(a: jax.Array, b: jax.Array, threshold: float,
             jax.ShapeDtypeStruct((N, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="pair_scores",
     )(a, b)
 
 
@@ -126,6 +127,7 @@ def pair_scores_compact(a_g: jax.Array, b_g: jax.Array,
         out_specs=pl.BlockSpec((1, bn, bm), lambda t: (t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((T, bn, bm), jnp.float32),
         interpret=interpret,
+        name="pair_scores_compact",
     )(a_g, b_g)
     ida = ida.reshape(-1)
     idb = idb.reshape(-1)

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
+
 from .kernel import pair_scores as _kernel_call
 from .ops import l2_normalize
 
@@ -163,19 +165,21 @@ def sharded_candidates(
     cap = min(cap, n_loc * m_loc)
     interpret = (impl == "interpret") or (
         impl == "auto" and jax.default_backend() != "tpu")
-    rows, cols, scores, dropped = _sharded_candidates_jit(
-        a, b, threshold=threshold, capacity=cap, mesh=mesh,
-        interpret=interpret)
-    rows = np.asarray(rows).reshape(-1)
-    cols = np.asarray(cols).reshape(-1)
-    scores = np.asarray(scores).reshape(-1)
+    with obs.span("join.machine.score"):
+        rows, cols, scores, dropped = _sharded_candidates_jit(
+            a, b, threshold=threshold, capacity=cap, mesh=mesh,
+            interpret=interpret)
+        rows = obs.to_host(rows).reshape(-1)
+        cols = obs.to_host(cols).reshape(-1)
+        scores = obs.to_host(scores).reshape(-1)
+        dropped = obs.to_host(dropped)
     keep = rows >= 0
     # padded rows/cols score 0 < threshold, so they can't appear as candidates
     return ShardedCandidates(
         rows=rows[keep].astype(np.int32),
         cols=cols[keep].astype(np.int32),
         scores=scores[keep].astype(np.float32),
-        n_dropped=int(np.asarray(dropped).sum()),
+        n_dropped=int(dropped.sum()),
         capacity=cap,
     )
 
@@ -281,7 +285,7 @@ class StreamingCandidateIndex:
         x = jnp.asarray(x, jnp.float32)
         if self.normalize:
             x = l2_normalize(x)
-        return np.asarray(x)
+        return obs.to_host(x)
 
     def _block(self, a: np.ndarray, b: np.ndarray, row0: int, col0: int):
         """Score one (already-normalized) block; offset indices to global."""

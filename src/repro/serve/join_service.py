@@ -69,13 +69,13 @@ from __future__ import annotations
 import collections
 import dataclasses
 import os
-import time
 from typing import Deque, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.crowd import CostModel, Crowd, CrowdGateway, LatencyModel, \
     PerfectCrowd
 from repro.core.jax_graph import (
@@ -148,7 +148,6 @@ class JoinSessionResult:
     n_hits: int
     cost_cents: float
     quality: Optional[Quality]
-    wall_seconds: float
     sim_minutes: Optional[float] = None  # gateway clock at completion
     # device-side answer-fold counter (SessionState.rounds): equals n_rounds
     # under the round barrier; under async ID/NF it counts poll events that
@@ -203,7 +202,6 @@ class _Lane:
     labels_host: np.ndarray        # (p,) int32 mirror for done/progress checks
     crowdsourced: np.ndarray       # (p,) bool, ordered
     round_sizes: List[int]
-    t0: float
     prior_host: np.ndarray         # (p_cap,) f32 machine likelihood, padded
     prior_dev: jax.Array           # device copy for single-lane dispatches
     adaptive: bool                 # live posterior re-ranking (DESIGN.md §10)
@@ -488,55 +486,57 @@ class JoinService:
         Admitted requests reserve their budget against the envelope; a
         request asking for more than remains (or for no cap at all) is
         clamped to the remainder and reports ``envelope_clamped``."""
-        remaining = None
-        if self.admission is not None:
-            pol = self.admission
-            if pol.max_pending is not None and \
-                    len(self.queue) >= pol.max_pending:
-                self.n_shed += 1
-                raise AdmissionError(
-                    f"admission queue full ({len(self.queue)} >= "
-                    f"max_pending={pol.max_pending}) — request shed; retry "
-                    "after sessions finish")
-            if pol.global_budget_cents is not None:
-                remaining = (pol.global_budget_cents - self._envelope_spent
-                             - self._envelope_reserved)
-                if remaining <= 1e-9:
+        with obs.span("join.admit", req.rid):
+            remaining = None
+            if self.admission is not None:
+                pol = self.admission
+                if pol.max_pending is not None and \
+                        len(self.queue) >= pol.max_pending:
                     self.n_shed += 1
                     raise AdmissionError(
-                        "crowd-budget envelope exhausted "
-                        f"({pol.global_budget_cents:.2f} cents committed) — "
-                        "request shed")
-        req.order = validate_order(self.order if req.order is None
-                                   else req.order)
-        if req.crowd is None:
-            req.crowd = PerfectCrowd()
-        if req.budget_cents is None:
-            req.budget_cents = self.budget_cents
-        if req.cost_per_assignment is None:
-            req.cost_per_assignment = self.cost_per_assignment
-        if req.seed_labels is not None and \
-                len(req.seed_labels) != len(req.pairs):
-            raise ValueError(
-                f"seed_labels length {len(req.seed_labels)} != pair count "
-                f"{len(req.pairs)} — seeds are per-pair verdicts in the "
-                "request's pair order")
-        if req.rid is None:
-            req.rid = self._next_rid
-        elif req.rid in self.results or \
-                any(r.rid == req.rid for r in self.queue):
-            raise ValueError(
-                f"duplicate join request rid {req.rid}: already "
-                f"{'served' if req.rid in self.results else 'queued'} — "
-                "pick a fresh rid (or omit it for an auto-assigned one)")
-        self._next_rid = max(self._next_rid, req.rid) + 1
-        if remaining is not None:
-            if req.budget_cents is None or req.budget_cents > remaining:
-                req.budget_cents = remaining
-                req.envelope_clamped = True
-            self._envelope_reserved += req.budget_cents
-        self.queue.append(req)
-        return req.rid
+                        f"admission queue full ({len(self.queue)} >= "
+                        f"max_pending={pol.max_pending}) — request shed; "
+                        "retry after sessions finish")
+                if pol.global_budget_cents is not None:
+                    remaining = (pol.global_budget_cents - self._envelope_spent
+                                 - self._envelope_reserved)
+                    if remaining <= 1e-9:
+                        self.n_shed += 1
+                        raise AdmissionError(
+                            "crowd-budget envelope exhausted "
+                            f"({pol.global_budget_cents:.2f} cents "
+                            "committed) — request shed")
+            req.order = validate_order(self.order if req.order is None
+                                       else req.order)
+            if req.crowd is None:
+                req.crowd = PerfectCrowd()
+            if req.budget_cents is None:
+                req.budget_cents = self.budget_cents
+            if req.cost_per_assignment is None:
+                req.cost_per_assignment = self.cost_per_assignment
+            if req.seed_labels is not None and \
+                    len(req.seed_labels) != len(req.pairs):
+                raise ValueError(
+                    f"seed_labels length {len(req.seed_labels)} != pair count "
+                    f"{len(req.pairs)} — seeds are per-pair verdicts in the "
+                    "request's pair order")
+            if req.rid is None:
+                req.rid = self._next_rid
+            elif req.rid in self.results or \
+                    any(r.rid == req.rid for r in self.queue):
+                raise ValueError(
+                    f"duplicate join request rid {req.rid}: already "
+                    f"{'served' if req.rid in self.results else 'queued'} — "
+                    "pick a fresh rid (or omit it for an auto-assigned one)")
+            self._next_rid = max(self._next_rid, req.rid) + 1
+            obs.set_rid(req.rid)
+            if remaining is not None:
+                if req.budget_cents is None or req.budget_cents > remaining:
+                    req.budget_cents = remaining
+                    req.envelope_clamped = True
+                self._envelope_reserved += req.budget_cents
+            self.queue.append(req)
+            return req.rid
 
     def submit(self, pairs: PairSet, crowd: Optional[Crowd] = None,
                order: Optional[str] = None, rid: Optional[int] = None,
@@ -548,11 +548,14 @@ class JoinService:
         ``order`` / ``budget_cents`` / ``cost_per_assignment`` default to the
         service-level settings when omitted.  ``seed_labels`` warm-starts the
         session from cached cross-query verdicts (DESIGN.md §14)."""
-        return self._admit(JoinRequest(
-            rid, pairs, crowd, order, total_true_matches,
-            budget_cents=budget_cents,
-            cost_per_assignment=cost_per_assignment,
-            seed_labels=seed_labels))
+        with obs.span("join.submit", rid):
+            rid = self._admit(JoinRequest(
+                rid, pairs, crowd, order, total_true_matches,
+                budget_cents=budget_cents,
+                cost_per_assignment=cost_per_assignment,
+                seed_labels=seed_labels))
+            obs.set_rid(rid)
+        return rid
 
     @staticmethod
     def _check_candidate_overflow(cand) -> None:
@@ -612,65 +615,71 @@ class JoinService:
         from repro.kernels.pair_scores.sharded import (
             StreamingCandidateIndex, sharded_candidates)
 
-        if streaming:
-            index = StreamingCandidateIndex(threshold, mesh,
-                                            capacity=capacity, impl=impl,
-                                            blocking=blocking)
-            cand = index.append(emb_a, emb_b)
-            if cand.n_dropped:
-                # reject atomically BEFORE surfacing the overflow: a raise
-                # that left the partially-compacted epoch in the index would
-                # make a retry at suggested_capacity score the corpus as
-                # "already seen" and return no candidates at all
-                index.rollback_append()
-        elif blocking is not None:
-            cand = blocked_candidates(emb_a, emb_b, threshold,
-                                      config=blocking, capacity=capacity,
-                                      impl=impl)
-        else:
-            cand = sharded_candidates(emb_a, emb_b, threshold, mesh,
-                                      capacity=capacity, impl=impl)
-        self._check_candidate_overflow(cand)
-        n_a = int(emb_a.shape[0])
-        n_b = int(emb_b.shape[0])
-        truth = None
-        if truth_fn is not None:
-            truth = np.asarray(truth_fn(cand.rows, cand.cols), bool)
-        pairs = PairSet(
-            u=cand.rows,
-            v=cand.cols + n_a,
-            likelihood=(cand.scores + 1.0) / 2.0,
-            truth=truth,
-            n_objects=n_a + n_b,
-        )
-        seed_labels = None
-        fps = None
-        if self.cluster_cache is not None:
-            # auto seed/deposit wiring (DESIGN.md §14/§16): fingerprint the
-            # candidate rows, warm-start from cached cross-query verdicts,
-            # and remember the fingerprints so _finalize can deposit this
-            # request's verdicts back.  An all-UNKNOWN seed is harmless —
-            # lane open skips the seed fold when nothing is known.
-            from repro.plan.algebra import row_fingerprints
-            fa = row_fingerprints(np.asarray(emb_a))
-            fb = row_fingerprints(np.asarray(emb_b))
-            fps = ([fa[int(i)] for i in np.asarray(cand.rows)],
-                   [fb[int(j)] for j in np.asarray(cand.cols)])
-            seed_labels = self.cluster_cache.seed(fps[0], fps[1])
-        rid = self._admit(JoinRequest(
-            None, pairs, crowd, order, total_true_matches,
-            budget_cents=budget_cents,
-            cost_per_assignment=cost_per_assignment,
-            seed_labels=seed_labels))
-        if fps is not None:
-            self._cache_fps[rid] = fps
-        if streaming:
-            self._streams[rid] = _EmbeddingStream(
-                index=index, truth_fn=truth_fn,
-                ids_a=np.arange(n_a, dtype=np.int32),
-                ids_b=np.arange(n_a, n_a + n_b, dtype=np.int32),
-                next_id=n_a + n_b)
-        return rid
+        with obs.span("join.submit"):
+            with obs.span("join.machine"):
+                if streaming:
+                    index = StreamingCandidateIndex(threshold, mesh,
+                                                    capacity=capacity,
+                                                    impl=impl,
+                                                    blocking=blocking)
+                    cand = index.append(emb_a, emb_b)
+                    if cand.n_dropped:
+                        # reject atomically BEFORE surfacing the overflow: a
+                        # raise that left the partially-compacted epoch in
+                        # the index would make a retry at suggested_capacity
+                        # score the corpus as "already seen" and return no
+                        # candidates at all
+                        index.rollback_append()
+                elif blocking is not None:
+                    cand = blocked_candidates(emb_a, emb_b, threshold,
+                                              config=blocking,
+                                              capacity=capacity, impl=impl)
+                else:
+                    cand = sharded_candidates(emb_a, emb_b, threshold, mesh,
+                                              capacity=capacity, impl=impl)
+                self._check_candidate_overflow(cand)
+            n_a = int(emb_a.shape[0])
+            n_b = int(emb_b.shape[0])
+            truth = None
+            if truth_fn is not None:
+                truth = np.asarray(truth_fn(cand.rows, cand.cols), bool)
+            pairs = PairSet(
+                u=cand.rows,
+                v=cand.cols + n_a,
+                likelihood=(cand.scores + 1.0) / 2.0,
+                truth=truth,
+                n_objects=n_a + n_b,
+            )
+            seed_labels = None
+            fps = None
+            if self.cluster_cache is not None:
+                # auto seed/deposit wiring (DESIGN.md §14/§16): fingerprint
+                # the candidate rows, warm-start from cached cross-query
+                # verdicts, and remember the fingerprints so _finalize can
+                # deposit this request's verdicts back.  An all-UNKNOWN seed
+                # is harmless — lane open skips the seed fold when nothing
+                # is known.
+                from repro.plan.algebra import row_fingerprints
+                fa = row_fingerprints(obs.to_host(emb_a))
+                fb = row_fingerprints(obs.to_host(emb_b))
+                fps = ([fa[int(i)] for i in np.asarray(cand.rows)],
+                       [fb[int(j)] for j in np.asarray(cand.cols)])
+                seed_labels = self.cluster_cache.seed(fps[0], fps[1])
+            rid = self._admit(JoinRequest(
+                None, pairs, crowd, order, total_true_matches,
+                budget_cents=budget_cents,
+                cost_per_assignment=cost_per_assignment,
+                seed_labels=seed_labels))
+            obs.set_rid(rid)
+            if fps is not None:
+                self._cache_fps[rid] = fps
+            if streaming:
+                self._streams[rid] = _EmbeddingStream(
+                    index=index, truth_fn=truth_fn,
+                    ids_a=np.arange(n_a, dtype=np.int32),
+                    ids_b=np.arange(n_a, n_a + n_b, dtype=np.int32),
+                    next_id=n_a + n_b)
+            return rid
 
     # -- streaming ingest (DESIGN.md §11) ------------------------------------
     def append(self, rid: int, pairs: PairSet) -> None:
@@ -768,58 +777,62 @@ class JoinService:
 
     # -- lane lifecycle ------------------------------------------------------
     def _open_lane(self, req: JoinRequest) -> _Lane:
-        perm = get_order(req.pairs, req.order)
-        ordered = req.pairs.take(perm)
-        P = len(ordered)
-        p_cap = _bucket(P)
-        n_cap = _bucket(ordered.n_objects)
-        # canonical pair keys are lo * n + hi; don't let bucketing push n_cap
-        # past the representable range when the raw size is still fine
-        if not pair_keys_fit(n_cap):
-            n_cap = ordered.n_objects
-        state = make_session_state(ordered.u, ordered.v, ordered.n_objects,
-                                  pair_capacity=p_cap, object_capacity=n_cap)
-        labels_host = np.full(P, UNKNOWN, np.int32)
-        n_cache_hits = 0
-        if req.seed_labels is not None:
-            # cross-query warm start (DESIGN.md §14): fold cached cluster
-            # verdicts before the first frontier, so seeded pairs (and
-            # whatever deduction reaches from them) never get crowdsourced.
-            # Seeds are never posted to the gateway — spend excludes them.
-            seeds = np.full(p_cap, UNKNOWN, np.int32)
-            seeds[:P] = np.asarray(req.seed_labels, np.int32)[perm]
-            if (seeds != UNKNOWN).any():
-                engine_dispatches.add()  # seed upload
-                state, cmask = session_seed_labels(state, jnp.asarray(seeds))
-                n_cache_hits = int(((seeds[:P] != UNKNOWN)
-                                    & ~np.asarray(cmask)[:P]).sum())
-                labels_host = np.asarray(state.labels)[:P]
-        prior_host = np.zeros(p_cap, np.float32)
-        prior_host[:P] = ordered.likelihood
-        rate = (req.cost_per_assignment if req.cost_per_assignment is not None
-                else self.cost.cents_per_assignment)
-        engine_dispatches.add()  # prior upload
-        return _Lane(
-            req=req,
-            perm=perm,
-            ordered=ordered,
-            p=P,
-            state=state,
-            labels_host=labels_host,
-            n_cache_hits=n_cache_hits,
-            crowdsourced=np.zeros(P, bool),
-            round_sizes=[],
-            t0=time.perf_counter(),
-            prior_host=prior_host,
-            prior_dev=jnp.asarray(prior_host),
-            adaptive=req.order == "adaptive",
-            rate_cents=float(rate),
-            per_pair_cents=float(rate)
-            * getattr(req.crowd, "n_assignments", 1),
-            budget_cents=req.budget_cents,
-            answers_host=req.crowd.precomputed_answers(ordered),
-            inflight_host=np.zeros(p_cap, bool),
-        )
+        with obs.span("join.open_lane", req.rid):
+            perm = get_order(req.pairs, req.order)
+            ordered = req.pairs.take(perm)
+            P = len(ordered)
+            p_cap = _bucket(P)
+            n_cap = _bucket(ordered.n_objects)
+            # canonical pair keys are lo * n + hi; don't let bucketing push
+            # n_cap past the representable range when the raw size is still
+            # fine
+            if not pair_keys_fit(n_cap):
+                n_cap = ordered.n_objects
+            state = make_session_state(ordered.u, ordered.v,
+                                       ordered.n_objects, pair_capacity=p_cap,
+                                       object_capacity=n_cap)
+            labels_host = np.full(P, UNKNOWN, np.int32)
+            n_cache_hits = 0
+            if req.seed_labels is not None:
+                # cross-query warm start (DESIGN.md §14): fold cached cluster
+                # verdicts before the first frontier, so seeded pairs (and
+                # whatever deduction reaches from them) never get crowdsourced.
+                # Seeds are never posted to the gateway — spend excludes them.
+                seeds = np.full(p_cap, UNKNOWN, np.int32)
+                seeds[:P] = np.asarray(req.seed_labels, np.int32)[perm]
+                if (seeds != UNKNOWN).any():
+                    engine_dispatches.add()  # seed upload
+                    state, cmask = session_seed_labels(state,
+                                                       jnp.asarray(seeds))
+                    n_cache_hits = int(((seeds[:P] != UNKNOWN)
+                                        & ~obs.to_host(cmask)[:P]).sum())
+                    labels_host = obs.to_host(state.labels)[:P]
+            prior_host = np.zeros(p_cap, np.float32)
+            prior_host[:P] = ordered.likelihood
+            rate = (req.cost_per_assignment
+                    if req.cost_per_assignment is not None
+                    else self.cost.cents_per_assignment)
+            engine_dispatches.add()  # prior upload
+            return _Lane(
+                req=req,
+                perm=perm,
+                ordered=ordered,
+                p=P,
+                state=state,
+                labels_host=labels_host,
+                n_cache_hits=n_cache_hits,
+                crowdsourced=np.zeros(P, bool),
+                round_sizes=[],
+                prior_host=prior_host,
+                prior_dev=jnp.asarray(prior_host),
+                adaptive=req.order == "adaptive",
+                rate_cents=float(rate),
+                per_pair_cents=float(rate)
+                * getattr(req.crowd, "n_assignments", 1),
+                budget_cents=req.budget_cents,
+                answers_host=req.crowd.precomputed_answers(ordered),
+                inflight_host=np.zeros(p_cap, bool),
+            )
 
     # -- lane growth (DESIGN.md §11) -----------------------------------------
     def _flush_stacks(self) -> None:
@@ -921,63 +934,65 @@ class JoinService:
     def _finalize(self, lane: _Lane, sim_minutes: Optional[float],
                   gateway: Optional[CrowdGateway]) -> None:
         req = lane.req
-        P = len(req.pairs)
-        labels = np.zeros(P, bool)
-        crowdsourced = np.zeros(P, bool)
-        labels[lane.perm] = lane.labels_host == POS
-        crowdsourced[lane.perm] = lane.crowdsourced
-        q = None
-        if req.pairs.truth is not None:
-            ttm = req.total_true_matches
-            if ttm is None:
-                ttm = int(req.pairs.truth.sum())
-            q = quality(req.pairs, labels, ttm)
-        n_crowd = int(crowdsourced.sum())
-        self.results[req.rid] = res = JoinSessionResult(
-            rid=req.rid,
-            labels=labels,
-            crowdsourced=crowdsourced,
-            n_rounds=len(lane.round_sizes),
-            round_sizes=lane.round_sizes,
-            n_hits=self.cost.n_hits(n_crowd),
-            cost_cents=self.cost.cost_cents(n_crowd),
-            quality=q,
-            wall_seconds=time.perf_counter() - lane.t0,
-            sim_minutes=sim_minutes,
-            fold_rounds=int(np.asarray(lane.state.rounds)),
-            n_conflicts=int(np.asarray(lane.state.conflicts)[:lane.p].sum()),
-            n_requeried=lane.n_requeried,
-            n_spent_cents=gateway.spent_cents(req.rid) if gateway else 0.0,
-            stopped_on_budget=lane.budget_stopped,
-            n_cache_hits=lane.n_cache_hits,
-            n_cluster_tasks=lane.n_cluster_tasks,
-            n_cluster_pairs=gateway.cluster_pairs(req.rid) if gateway else 0,
-            n_cluster_cents=lane.n_cluster_cents,
-            admission_deferred=req.admission_deferred,
-            envelope_clamped=req.envelope_clamped,
-        )
-        # cross-query deposit (DESIGN.md §14/§16): hand the finished
-        # session's verdicts to the cluster cache under the fingerprints
-        # recorded at submit, then persist atomically.  UNKNOWN verdicts
-        # (budget-stopped pairs) deposit nothing; pairs appended after
-        # submit have no fingerprints and are sliced off.
-        fps = self._cache_fps.pop(req.rid, None)
-        if fps is not None and self.cluster_cache is not None:
-            verdicts = np.full(P, UNKNOWN, np.int32)
-            verdicts[lane.perm] = lane.labels_host
-            self.cluster_cache.deposit(fps[0], fps[1],
-                                       verdicts[: len(fps[0])])
-            if self.cache_path is not None:
-                self.cluster_cache.save(self.cache_path)
-        # admission envelope (DESIGN.md §16): the reservation made at admit
-        # converts into realized spend — the difference returns to the pool
-        if self.admission is not None and \
-                self.admission.global_budget_cents is not None:
-            self._envelope_reserved = max(
-                0.0, self._envelope_reserved - (req.budget_cents or 0.0))
-            self._envelope_spent += res.n_spent_cents
-        self._streams.pop(req.rid, None)
-        self._stream_interleave.pop(req.rid, None)
+        with obs.span("join.finalize", req.rid):
+            P = len(req.pairs)
+            labels = np.zeros(P, bool)
+            crowdsourced = np.zeros(P, bool)
+            labels[lane.perm] = lane.labels_host == POS
+            crowdsourced[lane.perm] = lane.crowdsourced
+            q = None
+            if req.pairs.truth is not None:
+                ttm = req.total_true_matches
+                if ttm is None:
+                    ttm = int(req.pairs.truth.sum())
+                q = quality(req.pairs, labels, ttm)
+            n_crowd = int(crowdsourced.sum())
+            self.results[req.rid] = res = JoinSessionResult(
+                rid=req.rid,
+                labels=labels,
+                crowdsourced=crowdsourced,
+                n_rounds=len(lane.round_sizes),
+                round_sizes=lane.round_sizes,
+                n_hits=self.cost.n_hits(n_crowd),
+                cost_cents=self.cost.cost_cents(n_crowd),
+                quality=q,
+                sim_minutes=sim_minutes,
+                fold_rounds=int(obs.to_host(lane.state.rounds)),
+                n_conflicts=int(
+                    obs.to_host(lane.state.conflicts)[:lane.p].sum()),
+                n_requeried=lane.n_requeried,
+                n_spent_cents=gateway.spent_cents(req.rid) if gateway else 0.0,
+                stopped_on_budget=lane.budget_stopped,
+                n_cache_hits=lane.n_cache_hits,
+                n_cluster_tasks=lane.n_cluster_tasks,
+                n_cluster_pairs=(gateway.cluster_pairs(req.rid) if gateway
+                                 else 0),
+                n_cluster_cents=lane.n_cluster_cents,
+                admission_deferred=req.admission_deferred,
+                envelope_clamped=req.envelope_clamped,
+            )
+            # cross-query deposit (DESIGN.md §14/§16): hand the finished
+            # session's verdicts to the cluster cache under the fingerprints
+            # recorded at submit, then persist atomically.  UNKNOWN verdicts
+            # (budget-stopped pairs) deposit nothing; pairs appended after
+            # submit have no fingerprints and are sliced off.
+            fps = self._cache_fps.pop(req.rid, None)
+            if fps is not None and self.cluster_cache is not None:
+                verdicts = np.full(P, UNKNOWN, np.int32)
+                verdicts[lane.perm] = lane.labels_host
+                self.cluster_cache.deposit(fps[0], fps[1],
+                                           verdicts[: len(fps[0])])
+                if self.cache_path is not None:
+                    self.cluster_cache.save(self.cache_path)
+            # admission envelope (DESIGN.md §16): the reservation made at admit
+            # converts into realized spend — the difference returns to the pool
+            if self.admission is not None and \
+                    self.admission.global_budget_cents is not None:
+                self._envelope_reserved = max(
+                    0.0, self._envelope_reserved - (req.budget_cents or 0.0))
+                self._envelope_spent += res.n_spent_cents
+            self._streams.pop(req.rid, None)
+            self._stream_interleave.pop(req.rid, None)
 
     def _retire_done(self, active: List[_Lane],
                      gateway: Optional[CrowdGateway]) -> List[_Lane]:
@@ -1052,9 +1067,9 @@ class JoinService:
                 # the refresh already wrote -gain into every pending pair's
                 # priority, and the frontier only selects pending pairs —
                 # read it back instead of paying a second gains dispatch
-                gains = -np.asarray(stacked.priority)
+                gains = -obs.to_host(stacked.priority)
             else:
-                gains = np.asarray(session_gains_batch(
+                gains = obs.to_host(session_gains_batch(
                     stacked, self._group_priors(key, lanes)))
             for b, lane in enumerate(lanes):
                 idx = np.nonzero(frontier[b])[0]
@@ -1084,11 +1099,11 @@ class JoinService:
         contention and let deduction label what the graph already pins down
         (``session_trust_graph``); the rest stay UNKNOWN and finalize as
         non-matching.  One dispatch."""
-        mask = np.asarray(lane.state.labels) == UNKNOWN
-        mask &= ~np.asarray(lane.state.published)
+        mask = obs.to_host(lane.state.labels) == UNKNOWN
+        mask &= ~obs.to_host(lane.state.published)
         engine_dispatches.add()  # mask upload
         lane.state = session_trust_graph(lane.state, jnp.asarray(mask))
-        lane.labels_host = np.asarray(lane.state.labels)[:lane.p]
+        lane.labels_host = obs.to_host(lane.state.labels)[:lane.p]
         lane.budget_stopped = True
 
     # -- cluster-task scheduling (DESIGN.md §15) -----------------------------
@@ -1211,18 +1226,21 @@ class JoinService:
             lane.inflight_host[cov] = True
             cents = (self.cost.cluster_task_cents(n_objects, lane.rate_cents)
                      * self.cluster_assignments)
-            gateway.post_cluster(
-                lane.req.rid, lane.ordered, cov, lane.req.crowd,
-                cents=cents, n_assignments=self.cluster_assignments,
-                pair_cents_per_assignment=lane.rate_cents)
+            with obs.span("join.gateway.post", lane.req.rid):
+                gateway.post_cluster(
+                    lane.req.rid, lane.ordered, cov, lane.req.crowd,
+                    cents=cents, n_assignments=self.cluster_assignments,
+                    pair_cents_per_assignment=lane.rate_cents)
             lane.n_cluster_tasks += 1
             lane.n_cluster_cents += cents
             total += len(cov)
         if len(pair_idx):
             lane.crowdsourced[pair_idx] = True
             lane.inflight_host[pair_idx] = True
-            gateway.post(lane.req.rid, lane.ordered, pair_idx, lane.req.crowd,
-                         cents_per_assignment=lane.rate_cents)
+            with obs.span("join.gateway.post", lane.req.rid):
+                gateway.post(lane.req.rid, lane.ordered, pair_idx,
+                             lane.req.crowd,
+                             cents_per_assignment=lane.rate_cents)
             total += len(pair_idx)
         return total
 
@@ -1265,71 +1283,77 @@ class JoinService:
         exits pre-fold with ``fused_ok=False`` (nothing posted for the
         conflicted round) and re-runs it through the exact legacy path.
         Returns True iff any lane made progress."""
-        self._flush_stacks()
-        p_cap = max(int(l.state.u.shape[0]) for l in active)
-        n_cap = max(l.state.n_objects for l in active)
-        for lane in active:
-            if (int(lane.state.u.shape[0]),
-                    lane.state.n_objects) != (p_cap, n_cap):
-                lane.state = session_grow(lane.state, p_cap, n_cap)
-        B = len(active)
-        stacked = _stack_states([l.state for l in active])
-        answers = np.full((B, p_cap), UNKNOWN, np.int32)
-        priors = np.zeros((B, p_cap), np.float32)
-        for b, lane in enumerate(active):
-            answers[b, :lane.p] = lane.answers_host[:lane.p]
-            priors[b, :len(lane.prior_host)] = lane.prior_host
-        engine_dispatches.add(2)  # answers + priors upload
-        answers_dev = jnp.asarray(answers)
-        priors_dev = jnp.asarray(priors)
-        adaptive = np.array([l.adaptive for l in active])
-        K = self.FUSED_ROUNDS_PER_DISPATCH
-        progress = False
-        running = True
-        while running:
-            stacked, crowd_new, sizes, rdone, codes = \
-                session_run_rounds_batch(stacked, answers_dev, K,
-                                         prior=priors_dev, adaptive=adaptive)
-            crowd_new = np.asarray(crowd_new)
-            sizes = np.asarray(sizes)
-            rdone = np.asarray(rdone)
-            codes = np.asarray(codes)
-            labels = np.asarray(stacked.labels)
-            running = False
-            stuck: List[int] = []
+        with obs.span("join.drive_fused"):
+            self._flush_stacks()
+            p_cap = max(int(l.state.u.shape[0]) for l in active)
+            n_cap = max(l.state.n_objects for l in active)
+            for lane in active:
+                if (int(lane.state.u.shape[0]),
+                        lane.state.n_objects) != (p_cap, n_cap):
+                    lane.state = session_grow(lane.state, p_cap, n_cap)
+            B = len(active)
+            stacked = _stack_states([l.state for l in active])
+            answers = np.full((B, p_cap), UNKNOWN, np.int32)
+            priors = np.zeros((B, p_cap), np.float32)
             for b, lane in enumerate(active):
-                for r in range(int(rdone[b])):
-                    lane.round_sizes.append(int(sizes[b, r]))
-                idx = np.nonzero(crowd_new[b, :lane.p])[0]
-                if len(idx):
-                    # replay the wave's gateway traffic: per-pair billing
-                    # and ask bookkeeping are order-independent, so one
-                    # post covers the rounds just simulated
-                    lane.crowdsourced[idx] = True
-                    gateway.post(lane.req.rid, lane.ordered, idx,
-                                 lane.req.crowd,
-                                 cents_per_assignment=lane.rate_cents)
-                    progress = True
-                new = labels[b, :lane.p]
-                progress |= bool((new != lane.labels_host).any())
-                lane.labels_host = new
-                code = int(codes[b])
-                if code == ROUNDS_CONFLICT:
-                    lane.fused_ok = False
-                elif (new == UNKNOWN).any():
-                    if code == ROUNDS_EMPTY:
-                        stuck.append(lane.req.rid)
-                    else:  # ROUNDS_RUNNING: wave continues next dispatch
-                        running = True
-            gateway.drain()  # consume the replayed posts (immediate mode)
-            if stuck:
-                raise RuntimeError(
-                    "join engine stuck: no frontier and nothing deducible "
-                    f"for rids {stuck}")
-        engine_dispatches.add()  # per-lane gathers out of the stack
-        for b, lane in enumerate(active):
-            lane.state = _index_state(stacked, b)
-        return progress
+                answers[b, :lane.p] = lane.answers_host[:lane.p]
+                priors[b, :len(lane.prior_host)] = lane.prior_host
+            engine_dispatches.add(2)  # answers + priors upload
+            answers_dev = jnp.asarray(answers)
+            priors_dev = jnp.asarray(priors)
+            adaptive = np.array([l.adaptive for l in active])
+            K = self.FUSED_ROUNDS_PER_DISPATCH
+            progress = False
+            running = True
+            while running:
+                with obs.span("join.engine_dispatch"):
+                    stacked, crowd_new, sizes, rdone, codes = \
+                        session_run_rounds_batch(stacked, answers_dev, K,
+                                                 prior=priors_dev,
+                                                 adaptive=adaptive)
+                    crowd_new = obs.to_host(crowd_new)
+                    sizes = obs.to_host(sizes)
+                    rdone = obs.to_host(rdone)
+                    codes = obs.to_host(codes)
+                    labels = obs.to_host(stacked.labels)
+                running = False
+                stuck: List[int] = []
+                for b, lane in enumerate(active):
+                    for r in range(int(rdone[b])):
+                        lane.round_sizes.append(int(sizes[b, r]))
+                    idx = np.nonzero(crowd_new[b, :lane.p])[0]
+                    if len(idx):
+                        # replay the wave's gateway traffic: per-pair billing
+                        # and ask bookkeeping are order-independent, so one
+                        # post covers the rounds just simulated
+                        lane.crowdsourced[idx] = True
+                        with obs.span("join.gateway.post", lane.req.rid):
+                            gateway.post(lane.req.rid, lane.ordered, idx,
+                                         lane.req.crowd,
+                                         cents_per_assignment=lane.rate_cents)
+                        progress = True
+                    new = labels[b, :lane.p]
+                    progress |= bool((new != lane.labels_host).any())
+                    lane.labels_host = new
+                    code = int(codes[b])
+                    if code == ROUNDS_CONFLICT:
+                        lane.fused_ok = False
+                    elif (new == UNKNOWN).any():
+                        if code == ROUNDS_EMPTY:
+                            stuck.append(lane.req.rid)
+                        else:  # ROUNDS_RUNNING: wave continues next dispatch
+                            running = True
+                # consume the replayed posts (immediate mode)
+                with obs.span("join.gateway.drain"):
+                    gateway.drain()
+                if stuck:
+                    raise RuntimeError(
+                        "join engine stuck: no frontier and nothing deducible "
+                        f"for rids {stuck}")
+            engine_dispatches.add()  # per-lane gathers out of the stack
+            for b, lane in enumerate(active):
+                lane.state = _index_state(stacked, b)
+            return progress
 
     def _step(self, active: List[_Lane], gateway: CrowdGateway) -> bool:
         """One engine round over the occupied lanes: an optional batched
@@ -1341,125 +1365,131 @@ class JoinService:
         been escalated to resolution (re-answered clean, or exhausted and
         trusted to the graph).  Returns True iff any lane made progress
         (crowdsourced, deduced, or budget-stopped at least one pair)."""
-        requery = self.conflict_policy == "requery"
-        groups: Dict[Tuple[int, int], List[_Lane]] = {}
-        for lane in active:
-            groups.setdefault(lane.bucket, []).append(lane)
-        staged = []
-        for key, lanes in groups.items():
-            stacked = self._group_stack(key, lanes)
-            if any(lane.adaptive for lane in lanes):
-                # fold posterior-refreshed priorities into the live states
-                # before selection (DESIGN.md §10), one dispatch per group
-                engine_dispatches.add()
-                stacked = session_refresh_priorities_batch(
-                    stacked, self._group_priors(key, lanes),
-                    np.array([l.adaptive for l in lanes]))
-            frontier = np.asarray(session_frontier_batch(stacked))
-            if self.cluster_tasks:
-                # the harvest planner widens the posted mask in place
-                frontier = np.array(frontier)
-            staged.append([key, lanes, stacked, frontier])
-        budget_stops = self._allocate(staged, gateway)
-        # cluster-task planning (DESIGN.md §15): split each lane's allocated
-        # frontier into cluster harvests + leftover pairs, and widen the
-        # posted mask with the harvested extras so the publish below gates
-        # deduction off every pair with an answer inbound
-        plans: Dict[Tuple[int, int], Tuple[list, np.ndarray]] = {}
-        for si, stage in enumerate(staged):
-            _, lanes, _, posted = stage
-            for b, lane in enumerate(lanes):
-                idx = np.nonzero(posted[b])[0]
-                if len(idx) == 0:
-                    continue
-                clusters, pair_idx = self._plan_tasks(lane, idx, gateway)
-                plans[(si, b)] = (clusters, pair_idx)
-                for _, cov in clusters:
-                    posted[b, cov] = True
-        for stage in staged:
-            key, lanes, stacked, posted = stage
-            if requery and posted.any():
-                # published bits gate the fused deduce off still-contested
-                # pairs, so a rejected answer can wait for its escalation
-                engine_dispatches.add()  # posted-mask upload
-                stacked = session_mark_published_batch(
-                    stacked, jnp.asarray(posted))
-                stage[2] = stacked
-        # post every lane's allocation, then drain: the barrier spans lanes
-        for si, (_, lanes, _, posted) in enumerate(staged):
-            for b, lane in enumerate(lanes):
-                plan = plans.get((si, b))
-                if plan is None:
-                    continue
-                n = self._post_lane(lane, plan[0], plan[1], gateway)
-                if n:
-                    lane.round_sizes.append(n)
-        # fold/escalate until no group has a conflict awaiting an answer
-        pending = True
-        while pending:
-            pending = False
-            answers: Dict[int, List] = {}
-            for ans in gateway.drain():
-                answers.setdefault(ans.rid, []).append(ans)
-            for stage in staged:
-                key, lanes, stacked, frontier = stage
-                B, p_cap = frontier.shape
-                updates = np.full((B, p_cap), UNKNOWN, np.int32)
-                landed = False
+        with obs.span("join.step"):
+            requery = self.conflict_policy == "requery"
+            groups: Dict[Tuple[int, int], List[_Lane]] = {}
+            for lane in active:
+                groups.setdefault(lane.bucket, []).append(lane)
+            staged = []
+            for key, lanes in groups.items():
+                stacked = self._group_stack(key, lanes)
+                if any(lane.adaptive for lane in lanes):
+                    # fold posterior-refreshed priorities into the live states
+                    # before selection (DESIGN.md §10), one dispatch per group
+                    engine_dispatches.add()
+                    stacked = session_refresh_priorities_batch(
+                        stacked, self._group_priors(key, lanes),
+                        np.array([l.adaptive for l in lanes]))
+                frontier = obs.to_host(session_frontier_batch(stacked))
+                if self.cluster_tasks:
+                    # the harvest planner widens the posted mask in place
+                    frontier = np.array(frontier)
+                staged.append([key, lanes, stacked, frontier])
+            budget_stops = self._allocate(staged, gateway)
+            # cluster-task planning (DESIGN.md §15): split each lane's
+            # allocated frontier into cluster harvests + leftover pairs, and
+            # widen the posted mask with the harvested extras so the publish
+            # below gates deduction off every pair with an answer inbound
+            plans: Dict[Tuple[int, int], Tuple[list, np.ndarray]] = {}
+            for si, stage in enumerate(staged):
+                _, lanes, _, posted = stage
                 for b, lane in enumerate(lanes):
-                    for ans in answers.get(lane.req.rid, ()):
-                        updates[b, ans.index] = ans.label
-                        lane.inflight_host[ans.index] = False
-                        landed = True
-                if not landed:
-                    continue  # nothing for this group this pass
-                engine_dispatches.add()  # updates upload
-                stacked, cmask = session_fold_answers_batch(
-                    stacked, jnp.asarray(updates),
-                    keep_conflicts_published=requery)
-                if requery:
-                    cmask = np.asarray(cmask)
-                    exhausted_mask = np.zeros(cmask.shape, bool)
-                    trust = False
+                    idx = np.nonzero(posted[b])[0]
+                    if len(idx) == 0:
+                        continue
+                    clusters, pair_idx = self._plan_tasks(lane, idx, gateway)
+                    plans[(si, b)] = (clusters, pair_idx)
+                    for _, cov in clusters:
+                        posted[b, cov] = True
+            for stage in staged:
+                key, lanes, stacked, posted = stage
+                if requery and posted.any():
+                    # published bits gate the fused deduce off still-contested
+                    # pairs, so a rejected answer can wait for its escalation
+                    engine_dispatches.add()  # posted-mask upload
+                    stacked = session_mark_published_batch(
+                        stacked, jnp.asarray(posted))
+                    stage[2] = stacked
+            # post every lane's allocation, then drain: the barrier spans lanes
+            for si, (_, lanes, _, posted) in enumerate(staged):
+                for b, lane in enumerate(lanes):
+                    plan = plans.get((si, b))
+                    if plan is None:
+                        continue
+                    n = self._post_lane(lane, plan[0], plan[1], gateway)
+                    if n:
+                        lane.round_sizes.append(n)
+            # fold/escalate until no group has a conflict awaiting an answer
+            pending = True
+            while pending:
+                pending = False
+                answers: Dict[int, List] = {}
+                with obs.span("join.gateway.drain"):
+                    drained = gateway.drain()
+                for ans in drained:
+                    answers.setdefault(ans.rid, []).append(ans)
+                for stage in staged:
+                    key, lanes, stacked, frontier = stage
+                    B, p_cap = frontier.shape
+                    updates = np.full((B, p_cap), UNKNOWN, np.int32)
+                    landed = False
                     for b, lane in enumerate(lanes):
-                        cidx = np.nonzero(cmask[b, :lane.p])[0]
-                        if len(cidx) == 0:
-                            continue
-                        ticket, exhausted = gateway.requery(
-                            lane.req.rid, lane.ordered, cidx, lane.req.crowd,
-                            cents_per_assignment=lane.rate_cents,
-                            budget_cents=lane.budget_cents)
-                        lane.n_requeried += len(ticket.indices)
-                        if ticket.indices:
-                            lane.inflight_host[list(ticket.indices)] = True
-                        pending |= bool(ticket.indices)
-                        if exhausted:
-                            exhausted_mask[b, exhausted] = True
-                            trust = True
-                    if trust:
-                        # escalation ladder exhausted: the graph outvotes
-                        # the crowd — un-publish + deduce in one dispatch
-                        stacked = session_trust_graph_batch(
-                            stacked, jnp.asarray(exhausted_mask))
-                stage[2] = stacked
-        progress = False
-        stop_set = set(id(l) for l in budget_stops)
-        for key, lanes, stacked, _ in staged:
-            self._stacks[key] = (tuple(lanes), stacked)
-            labels = np.asarray(stacked.labels)
-            for b, lane in enumerate(lanes):
-                new = labels[b, :lane.p]
-                progress |= bool((new != lane.labels_host).any())
-                lane.labels_host = new
-                if id(lane) in stop_set and (new == UNKNOWN).any():
-                    # budget exhausted with pairs still open: trust the
-                    # graph for the remainder (DESIGN.md §10) and finalize
-                    lane.state = _index_state(stacked, b)
-                    self._budget_stop(lane)
-                    progress = True
-                elif lane.done:  # leaving the group: materialize its state
-                    lane.state = _index_state(stacked, b)
-        return progress
+                        for ans in answers.get(lane.req.rid, ()):
+                            updates[b, ans.index] = ans.label
+                            lane.inflight_host[ans.index] = False
+                            landed = True
+                    if not landed:
+                        continue  # nothing for this group this pass
+                    engine_dispatches.add()  # updates upload
+                    stacked, cmask = session_fold_answers_batch(
+                        stacked, jnp.asarray(updates),
+                        keep_conflicts_published=requery)
+                    if requery:
+                        cmask = obs.to_host(cmask)
+                        exhausted_mask = np.zeros(cmask.shape, bool)
+                        trust = False
+                        for b, lane in enumerate(lanes):
+                            cidx = np.nonzero(cmask[b, :lane.p])[0]
+                            if len(cidx) == 0:
+                                continue
+                            with obs.span("join.gateway.post",
+                                          lane.req.rid):
+                                ticket, exhausted = gateway.requery(
+                                    lane.req.rid, lane.ordered, cidx,
+                                    lane.req.crowd,
+                                    cents_per_assignment=lane.rate_cents,
+                                    budget_cents=lane.budget_cents)
+                            lane.n_requeried += len(ticket.indices)
+                            if ticket.indices:
+                                lane.inflight_host[list(ticket.indices)] = True
+                            pending |= bool(ticket.indices)
+                            if exhausted:
+                                exhausted_mask[b, exhausted] = True
+                                trust = True
+                        if trust:
+                            # escalation ladder exhausted: the graph outvotes
+                            # the crowd — un-publish + deduce in one dispatch
+                            stacked = session_trust_graph_batch(
+                                stacked, jnp.asarray(exhausted_mask))
+                    stage[2] = stacked
+            progress = False
+            stop_set = set(id(l) for l in budget_stops)
+            for key, lanes, stacked, _ in staged:
+                self._stacks[key] = (tuple(lanes), stacked)
+                labels = obs.to_host(stacked.labels)
+                for b, lane in enumerate(lanes):
+                    new = labels[b, :lane.p]
+                    progress |= bool((new != lane.labels_host).any())
+                    lane.labels_host = new
+                    if id(lane) in stop_set and (new == UNKNOWN).any():
+                        # budget exhausted with pairs still open: trust the
+                        # graph for the remainder (DESIGN.md §10) and finalize
+                        lane.state = _index_state(stacked, b)
+                        self._budget_stop(lane)
+                        progress = True
+                    elif lane.done:  # leaving the group: materialize its state
+                        lane.state = _index_state(stacked, b)
+            return progress
 
     # -- asynchronous ID/NF engine -------------------------------------------
     def _publish(self, lane: _Lane, gateway: CrowdGateway) -> int:
@@ -1473,7 +1503,7 @@ class JoinService:
         if lane.adaptive:
             lane.state = session_refresh_priorities(lane.state,
                                                     lane.prior_dev)
-        frontier = np.asarray(session_frontier(lane.state))
+        frontier = obs.to_host(session_frontier(lane.state))
         idx = np.nonzero(frontier)[0]
         if len(idx) == 0:
             return 0
@@ -1485,9 +1515,10 @@ class JoinService:
             if lane.adaptive:
                 # the refresh above already wrote -gain into every pending
                 # pair's priority — read it back, no second dispatch
-                gains = -np.asarray(lane.state.priority)
+                gains = -obs.to_host(lane.state.priority)
             else:
-                gains = np.asarray(session_gains(lane.state, lane.prior_dev))
+                gains = obs.to_host(session_gains(lane.state,
+                                                  lane.prior_dev))
             idx = idx[np.argsort(-gains[idx], kind="stable")][:afford]
             frontier = np.zeros_like(frontier)
             frontier[idx] = True
@@ -1509,7 +1540,7 @@ class JoinService:
         """Deduce everything the lane's evidence pins down (skipping pairs
         whose answers are still in flight) and refresh the host mirror."""
         lane.state = session_deduce(lane.state)
-        lane.labels_host = np.asarray(lane.state.labels)[:lane.p]
+        lane.labels_host = obs.to_host(lane.state.labels)[:lane.p]
 
     def _handle_conflicts(self, lane: _Lane, cidx: np.ndarray,
                           gateway: CrowdGateway) -> None:
@@ -1519,10 +1550,11 @@ class JoinService:
         Under the drop policy the fold already settled them — nothing to do."""
         if self.conflict_policy != "requery":
             return
-        ticket, exhausted = gateway.requery(
-            lane.req.rid, lane.ordered, cidx, lane.req.crowd,
-            cents_per_assignment=lane.rate_cents,
-            budget_cents=lane.budget_cents)
+        with obs.span("join.gateway.post", lane.req.rid):
+            ticket, exhausted = gateway.requery(
+                lane.req.rid, lane.ordered, cidx, lane.req.crowd,
+                cents_per_assignment=lane.rate_cents,
+                budget_cents=lane.budget_cents)
         lane.n_requeried += len(ticket.indices)
         lane.in_flight += len(ticket.indices)
         if ticket.indices:
@@ -1574,7 +1606,8 @@ class JoinService:
                 for lane in active:
                     if lane.in_flight == 0 and not lane.round_sizes:
                         self._publish(lane, gateway)
-            answers = gateway.poll()
+            with obs.span("join.gateway.drain"):
+                answers = gateway.poll()
             if not answers:
                 if not active and not gateway.in_flight:
                     continue  # queue may still refill
@@ -1625,7 +1658,7 @@ class JoinService:
                     lane.state, cmask = session_apply_answers(
                         lane.state, jnp.asarray(updates),
                         keep_conflicts_published=keep_pub)
-                cidx = np.nonzero(np.asarray(cmask)[:lane.p])[0]
+                cidx = np.nonzero(obs.to_host(cmask)[:lane.p])[0]
                 if len(cidx):
                     self._handle_conflicts(lane, cidx, gateway)
                     if not fold_now:
@@ -1634,7 +1667,7 @@ class JoinService:
                         # returned label read MATCH — deduce + re-select
                         self._sweep_lane(lane)
                         fold_now = True
-                lane.labels_host = np.asarray(lane.state.labels)[:lane.p]
+                lane.labels_host = obs.to_host(lane.state.labels)[:lane.p]
                 if fold_now and not lane.done:
                     self._publish(lane, gateway)
             active = self._retire_done(active, gateway)
@@ -1675,9 +1708,10 @@ class JoinService:
         states are authoritative; flushing is a pure writeback, so the
         capture never perturbs the run's semantics."""
         from repro.serve import recovery
-        self._flush_stacks()
-        tree, side = recovery.capture_service(self, active, gateway)
-        self._ckpt.save(self._ckpt_step, tree, sidecar=side)
+        with obs.span("join.checkpoint"):
+            self._flush_stacks()
+            tree, side = recovery.capture_service(self, active, gateway)
+            self._ckpt.save(self._ckpt_step, tree, sidecar=side)
         self._ckpt_step += 1
         if self._crash_after_checkpoints is not None and \
                 self._ckpt_step >= self._crash_after_checkpoints:
@@ -1706,47 +1740,50 @@ class JoinService:
     def run(self) -> Dict[int, JoinSessionResult]:
         """Drain the queue: lanes are refilled the moment a session finishes
         (continuous batching).  Returns {rid: result} for everything served."""
-        if self.async_mode:
-            return self._run_async()
-        gateway, active = self._resume_run_state()
-        self._stacks.clear()  # drop any cache left by an aborted run
-        self._prior_stacks.clear()
-        while self.queue or active:
-            self._checkpoint_tick(active, gateway)
-            while self.queue and len(active) < self.lanes:
-                active.append(self._open_lane(self.queue.popleft()))
-            for r in self.queue:  # still queued behind fully-occupied lanes
-                r.admission_deferred = True
-            if any(self._pending_arrivals.get(l.req.rid) for l in active):
-                # arrival epochs land before the round's frontier: lane
-                # states must be authoritative (not cached in a group
-                # stack) while they grow and re-bucket.  Arrivals for rids
-                # still waiting in the queue don't disturb the group caches.
-                self._flush_stacks()
-                for lane in active:
-                    self._ingest_pending(lane)
-            # zero-pair sessions are born done — finalize without a step
-            active = self._retire_done(active, gateway)
-            if not active:
-                continue
-            if all(lane.done for lane in active):
-                # every open lane is just waiting on queued arrival epochs
-                # (interleaved streams); ingest resumes next iteration
-                continue
-            if all(self._fused_eligible(lane) for lane in active):
-                # on-device round engine (DESIGN.md §13): the whole crowd
-                # wave runs as megabatch dispatches across all lanes.  No
-                # progress means every lane conflicted on its next round —
-                # fall through to the exact per-round path, which replays
-                # that round with the full §9 conflict machinery.
-                if self._drive_fused(active, gateway):
-                    active = self._retire_done(active, gateway)
+        with obs.span("join.run"):
+            if self.async_mode:
+                return self._run_async()
+            gateway, active = self._resume_run_state()
+            self._stacks.clear()  # drop any cache left by an aborted run
+            self._prior_stacks.clear()
+            while self.queue or active:
+                self._checkpoint_tick(active, gateway)
+                while self.queue and len(active) < self.lanes:
+                    active.append(self._open_lane(self.queue.popleft()))
+                # still queued behind fully-occupied lanes
+                for r in self.queue:
+                    r.admission_deferred = True
+                if any(self._pending_arrivals.get(l.req.rid) for l in active):
+                    # arrival epochs land before the round's frontier: lane
+                    # states must be authoritative (not cached in a group
+                    # stack) while they grow and re-bucket.  Arrivals for
+                    # rids still waiting in the queue don't disturb the group
+                    # caches.
+                    self._flush_stacks()
+                    for lane in active:
+                        self._ingest_pending(lane)
+                # zero-pair sessions are born done — finalize without a step
+                active = self._retire_done(active, gateway)
+                if not active:
                     continue
-            if not self._step(active, gateway):
-                raise RuntimeError(
-                    "join engine stuck: no frontier and nothing deducible "
-                    f"for rids {[l.req.rid for l in active]}")
-            active = self._retire_done(active, gateway)
-        self._stacks.clear()
-        self._prior_stacks.clear()
+                if all(lane.done for lane in active):
+                    # every open lane is just waiting on queued arrival epochs
+                    # (interleaved streams); ingest resumes next iteration
+                    continue
+                if all(self._fused_eligible(lane) for lane in active):
+                    # on-device round engine (DESIGN.md §13): the whole crowd
+                    # wave runs as megabatch dispatches across all lanes.  No
+                    # progress means every lane conflicted on its next round —
+                    # fall through to the exact per-round path, which replays
+                    # that round with the full §9 conflict machinery.
+                    if self._drive_fused(active, gateway):
+                        active = self._retire_done(active, gateway)
+                        continue
+                if not self._step(active, gateway):
+                    raise RuntimeError(
+                        "join engine stuck: no frontier and nothing deducible "
+                        f"for rids {[l.req.rid for l in active]}")
+                active = self._retire_done(active, gateway)
+            self._stacks.clear()
+            self._prior_stacks.clear()
         return dict(self.results)

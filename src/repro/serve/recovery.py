@@ -30,7 +30,6 @@ Known limitations, by design:
 from __future__ import annotations
 
 import collections
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -127,7 +126,6 @@ def _lane_meta(lane) -> dict:
         "n_cache_hits": int(lane.n_cache_hits),
         "n_cluster_tasks": int(lane.n_cluster_tasks),
         "n_cluster_cents": float(lane.n_cluster_cents),
-        "elapsed": float(time.perf_counter() - lane.t0),
     }
 
 
@@ -154,7 +152,6 @@ def _lane_from(service, arrays: Dict[str, Any], meta: dict):
         labels_host=np.asarray(arrays["labels"], np.int32),
         crowdsourced=np.asarray(arrays["crowdsourced"], bool),
         round_sizes=list(meta["round_sizes"]),
-        t0=time.perf_counter() - float(meta["elapsed"]),
         prior_host=prior_host,
         prior_dev=jnp.asarray(prior_host),
         adaptive=req.order == "adaptive",
@@ -194,7 +191,6 @@ def _result_meta(res) -> dict:
         "n_hits": int(res.n_hits),
         "cost_cents": float(res.cost_cents),
         "quality": q,
-        "wall_seconds": float(res.wall_seconds),
         "sim_minutes": (None if res.sim_minutes is None
                         else float(res.sim_minutes)),
         "fold_rounds": int(res.fold_rounds),
@@ -223,7 +219,6 @@ def _result_from(arrays: Dict[str, np.ndarray], meta: dict):
         n_hits=int(meta["n_hits"]),
         cost_cents=float(meta["cost_cents"]),
         quality=None if q is None else Quality(**q),
-        wall_seconds=float(meta["wall_seconds"]),
         sim_minutes=meta["sim_minutes"],
         fold_rounds=int(meta["fold_rounds"]),
         n_conflicts=int(meta["n_conflicts"]),

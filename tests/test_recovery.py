@@ -137,6 +137,40 @@ def test_restore_brings_back_results_queue_and_sidecar(tmp_path):
         assert res.quality == expected[r].quality
 
 
+def test_restore_ignores_retired_timing_fields(tmp_path):
+    """A checkpoint written while lanes kept ``elapsed`` and results kept
+    ``wall_seconds`` in the sidecar restores as if they were absent."""
+    import glob
+    import json
+
+    svc = JoinService(lanes=2, checkpoint_dir=str(tmp_path))
+    rids = _submit_all(svc)
+    # the 7th checkpoint holds two finished results and one open lane
+    svc._crash_after_checkpoints = 7
+    with pytest.raises(ServiceKilled):
+        svc.run()
+    for path in glob.glob(os.path.join(str(tmp_path), "*", "sidecar.json")):
+        with open(path) as f:
+            side = json.load(f)
+        for lane in side.get("lanes", []):
+            lane["elapsed"] = 1.5
+        for res in side.get("results", {}).values():
+            res["wall_seconds"] = 2.5
+        with open(path, "w") as f:
+            json.dump(side, f)
+    restored = JoinService.restore(str(tmp_path))
+    info = restored.last_recovery
+    assert info["n_results"] >= 1 and info["n_lanes"] >= 1
+    out = restored.run()
+    base = JoinService(lanes=2)
+    _submit_all(base)
+    expected = base.run()
+    for r in rids:
+        np.testing.assert_array_equal(expected[r].labels, out[r].labels)
+        assert expected[r].n_spent_cents == out[r].n_spent_cents
+        assert not hasattr(out[r], "wall_seconds")
+
+
 def test_restore_streaming_arrivals(tmp_path):
     """Pending arrival epochs (submit_stream) survive the kill: the
     restored run ingests them and matches the uninterrupted stream run."""
