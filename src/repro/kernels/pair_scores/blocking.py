@@ -31,6 +31,7 @@ CI smoke assert on (cells scored vs dense cells, tiles, duplicates).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import List, Optional, Tuple
 
@@ -70,7 +71,7 @@ class BlockingConfig:
     def __post_init__(self):
         if not 1 <= self.n_bits <= 30:
             raise ValueError(
-                f"n_bits must be in [1, 30] (codes pack into int64 and "
+                f"n_bits must be in [1, 30] (codes pack into int32 and "
                 f"2**30 buckets is already past any useful grain), got "
                 f"{self.n_bits}")
         if self.n_tables < 1:
@@ -121,21 +122,55 @@ def expected_recall(config: BlockingConfig, similarity: float) -> float:
     return 1.0 - (1.0 - p) ** config.n_tables
 
 
+# Rows per call of the signature program.  One fixed shape gives a row the
+# same compiled projection whichever call or slice it arrives in, so
+# streaming arrivals hash into the corpus's buckets bit for bit.
+_SIGNATURE_ROWS = 4096
+
+
+@functools.lru_cache(maxsize=64)
+def _planes(seed: int, dim: int, n_tables: int, n_bits: int) -> jax.Array:
+    """The seeded hyperplanes on the device as one (D, n_tables * n_bits)
+    matrix: column ``l * n_bits + j`` is table l's j-th plane."""
+    rng = np.random.default_rng(seed)
+    planes = rng.normal(size=(n_tables, dim, n_bits)).astype(np.float32)
+    return jnp.asarray(planes.transpose(1, 0, 2).reshape(dim, -1))
+
+
+@functools.partial(jax.jit, static_argnames=("n_bits",))
+def lsh_signatures(x: jax.Array, planes: jax.Array, n_bits: int) -> jax.Array:
+    """(n_tables, R) int32 codes of R rows: the sign bits of each table's
+    ``n_bits`` projections, bit j from plane j.  HIGHEST keeps the float32
+    projection, so a sign flips only within rounding of zero."""
+    proj = jnp.dot(x, planes, precision=jax.lax.Precision.HIGHEST)
+    bits = (proj >= 0.0).astype(jnp.int32).reshape(x.shape[0], -1, n_bits)
+    return jnp.sum(bits << jnp.arange(n_bits, dtype=jnp.int32), axis=-1).T
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _row_chunks(x: jax.Array, rows: int) -> Tuple[jax.Array, ...]:
+    """``x`` zero-padded to whole chunks of ``rows`` rows, cut into them."""
+    k = max(1, -(-x.shape[0] // rows))
+    x = jnp.pad(x, ((0, k * rows - x.shape[0]), (0, 0)))
+    return tuple(x[i * rows:(i + 1) * rows] for i in range(k))
+
+
 def signatures(x, config: BlockingConfig) -> np.ndarray:
     """(n_tables, N) int64 bucket codes: sign bits of ``n_bits`` seeded
     random hyperplane projections, packed per table.  Deterministic in
     (seed, D, n_bits, n_tables) alone, so rows hashed in different calls
     (streaming arrivals vs the original corpus) land in the same buckets.
-    Feed the *normalized* embeddings so batch and streaming paths see
-    bit-identical projections."""
+    Feed the *normalized* embeddings, a numpy array or a ``jax.Array``:
+    the codes are computed on the device in fixed row chunks
+    (:func:`lsh_signatures`) and read back once."""
     with obs.span("join.machine.signatures"):
-        x = np.asarray(obs.to_host(x), np.float32)
-        rng = np.random.default_rng(config.seed)
-        planes = rng.normal(size=(config.n_tables, x.shape[1],
-                                  config.n_bits)).astype(np.float32)
-        bits = np.einsum("nd,ldb->lnb", x, planes) >= 0.0
-        weights = (np.int64(1) << np.arange(config.n_bits, dtype=np.int64))
-        return bits @ weights
+        x = jnp.asarray(x, jnp.float32)
+        planes = _planes(config.seed, x.shape[1], config.n_tables,
+                         config.n_bits)
+        codes = [lsh_signatures(c, planes, config.n_bits)
+                 for c in _row_chunks(x, _SIGNATURE_ROWS)]
+        codes = np.concatenate(obs.to_host(codes), axis=1)
+        return codes[:, :x.shape[0]].astype(np.int64)
 
 
 def _pad_chunks(rows: np.ndarray, tile: int) -> np.ndarray:
@@ -336,9 +371,9 @@ def blocked_candidates(a, b, threshold: float,
         b = l2_normalize(jnp.asarray(b, jnp.float32))
     codes_a = signatures(a, config)
     codes_b = signatures(b, config)
-    tiles_a, tiles_b = block_pairs(
-        codes_a, np.arange(obs.to_host(a).shape[0]),
-        codes_b, np.arange(obs.to_host(b).shape[0]), config.bn, config.bm)
+    tiles_a, tiles_b = block_pairs(codes_a, np.arange(a.shape[0]),
+                                   codes_b, np.arange(b.shape[0]),
+                                   config.bn, config.bm)
     return score_block_pairs(a, b, tiles_a, tiles_b, threshold, config,
                              capacity=capacity, impl=impl)
 

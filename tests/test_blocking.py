@@ -22,6 +22,9 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro import obs
+from repro.core.jax_graph import next_pow2
+from repro.kernels.pair_scores import blocking
 from repro.kernels.pair_scores.blocking import (BlockingConfig,
                                                 blocked_candidates,
                                                 blocker_recall,
@@ -224,6 +227,73 @@ def test_signatures_deterministic_and_seed_sensitive():
     half = np.concatenate([signatures(a[:17], cfg),
                            signatures(a[17:], cfg)], axis=1)
     np.testing.assert_array_equal(half, s1)
+
+
+def _reference_projection(x, cfg):
+    """(n_tables, N, n_bits) float64 projections on the same seeded planes."""
+    rng = np.random.default_rng(cfg.seed)
+    planes = rng.normal(size=(cfg.n_tables, x.shape[1], cfg.n_bits)
+                        ).astype(np.float32)
+    return np.einsum("nd,ldb->lnb", x.astype(np.float64),
+                     planes.astype(np.float64))
+
+
+def test_signature_codes_match_float64_reference():
+    """The device codes are the sign bits of the seeded projections: equal
+    to a float64 reference wherever no projection lies within rounding of
+    zero (a sign there may go either way)."""
+    a, _ = _corpus(8, n_a=300, dim=32)
+    cfg = BlockingConfig(n_bits=7, n_tables=9)
+    proj = _reference_projection(a, cfg)
+    ref = (proj >= 0.0) @ (np.int64(1) << np.arange(cfg.n_bits))
+    clear = (np.abs(proj) > 1e-5).all(axis=2)
+    assert clear.mean() > 0.99
+    np.testing.assert_array_equal(signatures(a, cfg)[clear], ref[clear])
+
+
+@pytest.mark.parametrize("n_bits", [1, 5, 30])
+def test_signature_codes_are_int64_tables_of_n_bits(n_bits):
+    a, _ = _corpus(9, n_a=50)
+    codes = signatures(a, BlockingConfig(n_bits=n_bits, n_tables=3))
+    assert codes.dtype == np.int64 and codes.shape == (3, len(a))
+    assert codes.min() >= 0 and codes.max() < 2 ** n_bits
+
+
+def test_signatures_numpy_and_device_inputs_agree():
+    a, _ = _corpus(10)
+    cfg = BlockingConfig(**CFG_KW)
+    np.testing.assert_array_equal(signatures(a, cfg),
+                                  signatures(jnp.asarray(a), cfg))
+
+
+ROWS = 8   # signature row chunk in the test below
+
+
+@pytest.mark.parametrize("cut", [1, ROWS - 1, ROWS + 1])
+def test_signatures_invariant_across_chunk_boundary(monkeypatch, cut):
+    """Rows hashed in two calls split anywhere, across the fixed row chunk
+    too, get the codes they get in one call: the streaming invariant."""
+    monkeypatch.setattr(blocking, "_SIGNATURE_ROWS", ROWS)
+    a, _ = _corpus(12, n_a=ROWS + 3)
+    cfg = BlockingConfig(**CFG_KW)
+    whole = signatures(a, cfg)
+    split = np.concatenate([signatures(a[:cut], cfg),
+                            signatures(jnp.asarray(a[cut:]), cfg)], axis=1)
+    np.testing.assert_array_equal(split, whole)
+
+
+def test_blocked_candidates_reads_back_codes_and_chunks_only():
+    """The blocked machine phase reads the host nothing but one array of
+    codes per side and one result per kernel chunk: no embedding."""
+    a, b = _corpus(14, n_a=60, n_b=56)
+    cfg = BlockingConfig.for_recall(0.95, TAU, **CFG_KW)
+    s0 = obs.host_syncs.count
+    cand = blocked_candidates(jnp.asarray(a), jnp.asarray(b), TAU, cfg)
+    syncs = obs.host_syncs.count - s0
+    n_chunks = -(-cand.n_tiles // min(cfg.tiles_per_call,
+                                       next_pow2(cand.n_tiles, floor=1)))
+    assert n_chunks >= 2
+    assert syncs == 2 + n_chunks
 
 
 def test_blocked_capacity_overflow_and_suggested_retry():

@@ -72,6 +72,18 @@ def test_compact_chunk_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_lsh_signatures_compiles_for_v5e(one_chip):
+    """One row chunk of the blocker's signature program at the benchmark's
+    configuration: D = 300, 16 tables of 6 bits."""
+    from repro.kernels.pair_scores.blocking import (_SIGNATURE_ROWS,
+                                                    lsh_signatures)
+
+    x = _spec((_SIGNATURE_ROWS, 300), jnp.float32, one_chip)
+    planes = _spec((300, 16 * 6), jnp.float32, one_chip)
+    compiled = lsh_signatures.lower(x, planes, n_bits=6).compile()
+    assert compiled.as_text().startswith("HloModule jit_lsh_signatures")
+
+
 def test_round_engine_batch_compiles_for_v5e(one_chip):
     """The fused round engine as the service dispatches it: 4 lanes of
     32,768 pairs over 1,024 objects, with 32-bit pair keys."""
