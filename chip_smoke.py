@@ -324,8 +324,8 @@ def main() -> None:
 
     log(f"device: {dev.device_kind} x{len(jax.devices())}; compile cache "
         f"{use_compile_cache(ROOT)}")
-    # int64 is emulated on a TPU: pair keys stay int32, which bounds a
-    # session at 46,340 objects (every session here is far below it)
+    # int64 is emulated on a TPU: pair keys stay int32, one word up to
+    # 46,340 objects and two words past it
     check(not jax.config.jax_enable_x64, "x64 off: int32 pair keys")
     Phase.listen()
     if args.chips == 4:

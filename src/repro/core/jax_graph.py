@@ -120,18 +120,23 @@ def next_pow2(n: int, floor: int = 1) -> int:
 
 
 def pair_key_bits() -> int:
-    """Usable bits for canonical ``lo * n + hi`` pair keys.
+    """Usable bits of a one-word ``lo * n + hi`` pair key.
 
     Under the default jax config int64 silently narrows to int32, so only 31
-    bits are available; with ``jax_enable_x64`` (production) the full 63-bit
-    positive range is usable."""
+    bits are available; with ``jax_enable_x64`` the full 63-bit positive
+    range is usable.  Larger universes take two-word keys
+    (``pair_keys_fit``)."""
     return 63 if jax.config.jax_enable_x64 else 31
 
 
 def pair_keys_fit(n_objects: int) -> bool:
-    """True iff an ``n_objects`` universe's pair keys are representable in
-    the current key dtype.  The single guard shared by ``canonical_keys``
-    and the serving layer's capacity bucketing (DESIGN.md §8)."""
+    """True iff an ``n_objects`` universe's pair keys fit one word of the
+    key dtype (``lo * n + hi``; up to 46,340 objects in int32).  Otherwise
+    the engine carries each key as two int32 words, a (2, P) array of lo
+    roots over hi roots sorted lexicographically (DESIGN.md §8).  The
+    choice is static, from a session's object capacity: shared by
+    ``canonical_keys``, the fresh-state builders and the serving layer's
+    capacity bucketing."""
     return n_objects * n_objects < 2 ** pair_key_bits()
 
 
@@ -145,17 +150,99 @@ def _key_sentinel() -> int:
     return int(np.iinfo(np.dtype(_key_dtype().dtype)).max)
 
 
+def _empty_keys(shape: Tuple[int, ...], n_objects: int) -> jax.Array:
+    """An all-sentinel neg-key index of ``shape`` (leading batch axes, then
+    the P slots) for an ``n_objects`` universe: one word per key where the
+    keys fit, else two int32 words (a pad key has both words at the int32
+    maximum, above every real key since object ids are int32)."""
+    if pair_keys_fit(n_objects):
+        return jnp.full(shape, _key_sentinel(), _key_dtype())
+    return jnp.full(shape[:-1] + (2,) + shape[-1:],
+                    np.iinfo(np.int32).max, jnp.int32)
+
+
 def canonical_keys(roots_u: jax.Array, roots_v: jax.Array, n_objects: int) -> jax.Array:
-    """Canonical ``lo * n + hi`` cluster-pair keys, range-guarded."""
+    """Canonical cluster-pair keys of ``(roots_u, roots_v)``: one word
+    ``lo * n + hi`` where ``pair_keys_fit(n_objects)``, else two int32
+    words stacked on a new leading axis (``[lo, hi]``), which compare
+    lexicographically as the one-word keys compare numerically."""
     if not pair_keys_fit(n_objects):
-        raise ValueError(
-            f"n_objects={n_objects} overflows {pair_key_bits() + 1}-bit pair "
-            "keys; enable jax_enable_x64 for large object universes"
-        )
+        return jnp.stack([jnp.minimum(roots_u, roots_v),
+                          jnp.maximum(roots_u, roots_v)]).astype(jnp.int32)
     kdt = _key_dtype()
     lo = jnp.minimum(roots_u, roots_v).astype(kdt)
     hi = jnp.maximum(roots_u, roots_v).astype(kdt)
     return lo * jnp.asarray(n_objects, kdt) + hi
+
+
+# Helpers over either key form.  A neg-key index is (P,) one-word keys or
+# (2, P) two-word keys; under ``vmap`` each function sees one session, so
+# ``ndim`` tells the forms apart.  The one-word branches are the
+# expressions the engine has always used.
+def _sentinel_of(keys: jax.Array) -> jax.Array:
+    return jnp.asarray(jnp.iinfo(keys.dtype).max, keys.dtype)
+
+
+def _per_key(mask: jax.Array, keys: jax.Array) -> jax.Array:
+    """``mask`` over key slots, broadcastable against ``keys``."""
+    return mask if keys.ndim == mask.ndim else mask[None]
+
+
+def _sort_keys(keys: jax.Array) -> jax.Array:
+    """Ascending sort; two-word keys sort lexicographically (lo, then hi)."""
+    if keys.ndim == 1:
+        return jnp.sort(keys)
+    return jnp.stack(jax.lax.sort((keys[0], keys[1]), num_keys=2))
+
+
+def _keys_equal(keys: jax.Array, other: jax.Array) -> jax.Array:
+    """Slot-wise key equality (both words of a two-word key)."""
+    if keys.ndim == 1:
+        return keys == other
+    return (keys[0] == other[0]) & (keys[1] == other[1])
+
+
+def _key_starts(sorted_keys: jax.Array) -> jax.Array:
+    """(P,) bool: True at each slot of a sorted index that holds a key
+    other than the slot before it (slot 0 always)."""
+    first = jnp.ones((1,), bool)
+    if sorted_keys.ndim == 1:
+        return jnp.concatenate([first, sorted_keys[1:] != sorted_keys[:-1]])
+    return jnp.concatenate(
+        [first, ~_keys_equal(sorted_keys[:, 1:], sorted_keys[:, :-1])])
+
+
+def _has_keys(sorted_keys: jax.Array) -> jax.Array:
+    """True iff a sorted index holds a real key (it would sit at slot 0)."""
+    if sorted_keys.ndim == 1:
+        return sorted_keys[0] != _sentinel_of(sorted_keys)
+    return sorted_keys[0, 0] != _sentinel_of(sorted_keys)
+
+
+def _search_wide(sorted_keys: jax.Array, queries: jax.Array,
+                 right: bool) -> jax.Array:
+    """``searchsorted`` over lexicographically sorted two-word keys: per
+    query, the first slot whose key is >= it (> it with ``right``), by a
+    fixed-length binary search over both words."""
+    n = sorted_keys.shape[-1]
+    s_lo, s_hi = sorted_keys[0], sorted_keys[1]
+    q_lo, q_hi = queries[0], queries[1]
+
+    def body(_, bounds):
+        lo, hi = bounds
+        mid = (lo + hi) // 2
+        m = jnp.minimum(mid, n - 1)
+        a, b = s_lo[m], s_hi[m]
+        below = (a < q_lo) | ((a == q_lo) & ((b <= q_hi) if right
+                                              else (b < q_hi)))
+        open_ = lo < hi
+        return (jnp.where(open_ & below, mid + 1, lo),
+                jnp.where(open_ & ~below, mid, hi))
+
+    lo = jnp.zeros(q_lo.shape, jnp.int32)
+    hi = jnp.full(q_lo.shape, n, jnp.int32)
+    lo, _ = jax.lax.fori_loop(0, int(n).bit_length(), body, (lo, hi))
+    return lo
 
 
 # ---------------------------------------------------------------------------
@@ -236,9 +323,9 @@ def connected_components_batch(u: jax.Array, v: jax.Array, mask: jax.Array,
 # ---------------------------------------------------------------------------
 def _neg_keys_impl(roots, u, v, neg_mask, n_objects: int) -> jax.Array:
     keys = canonical_keys(roots[u], roots[v], n_objects)
-    sentinel = jnp.asarray(jnp.iinfo(keys.dtype).max, keys.dtype)
-    keys = jnp.where(neg_mask, keys, sentinel)
-    return jnp.sort(keys)
+    sentinel = _sentinel_of(keys)
+    keys = jnp.where(_per_key(neg_mask, keys), keys, sentinel)
+    return _sort_keys(keys)
 
 
 @engine_jit("neg_keys", static_argnames=("n_objects",))
@@ -255,15 +342,24 @@ def neg_keys(roots: jax.Array, u: jax.Array, v: jax.Array, neg_mask: jax.Array,
 
 
 def _in_sorted(sorted_keys: jax.Array, queries: jax.Array) -> jax.Array:
-    idx = jnp.searchsorted(sorted_keys, queries)
-    idx = idx.clip(0, sorted_keys.shape[0] - 1)
-    return sorted_keys[idx] == queries
+    if sorted_keys.ndim == 2:
+        idx = _search_wide(sorted_keys, queries, right=False)
+    else:
+        idx = jnp.searchsorted(sorted_keys, queries)
+    idx = idx.clip(0, sorted_keys.shape[-1] - 1)
+    return _keys_equal(sorted_keys[..., idx], queries)
 
 
 def _decompose_keys(keys: jax.Array, n_objects: int):
-    """Split canonical ``lo * n + hi`` keys back into endpoint ids.
-    Returns (lo, hi, is_pad); pad slots decompose to (0, 0)."""
-    sentinel = jnp.asarray(jnp.iinfo(keys.dtype).max, keys.dtype)
+    """Split canonical keys back into endpoint ids: ``lo * n + hi`` by
+    division, two-word keys by taking their words.  Returns (lo, hi,
+    is_pad); pad slots decompose to (0, 0)."""
+    sentinel = _sentinel_of(keys)
+    if keys.ndim == 2:
+        is_pad = keys[0] == sentinel
+        lo = jnp.where(is_pad, 0, keys[0])
+        hi = jnp.where(is_pad, 0, keys[1])
+        return lo.clip(0, n_objects - 1), hi.clip(0, n_objects - 1), is_pad
     is_pad = keys == sentinel
     nn = jnp.asarray(n_objects, keys.dtype)
     lo = jnp.where(is_pad, 0, keys // nn).astype(jnp.int32)
@@ -278,12 +374,11 @@ def _rekey_impl(sorted_keys: jax.Array, roots: jax.Array,
     A key whose endpoints were untouched maps to itself; sentinels stay
     sentinels.  The resulting multiset equals a from-scratch rebuild under the
     new roots (DESIGN.md §8 invariant)."""
-    kdt = sorted_keys.dtype
-    sentinel = jnp.asarray(jnp.iinfo(kdt).max, kdt)
+    sentinel = _sentinel_of(sorted_keys)
     lo, hi, is_pad = _decompose_keys(sorted_keys, n_objects)
     new = canonical_keys(roots[lo], roots[hi], n_objects)
-    new = jnp.where(is_pad, sentinel, new)
-    return jnp.sort(new)
+    new = jnp.where(_per_key(is_pad, new), sentinel, new)
+    return _sort_keys(new)
 
 
 def _merge_sorted_impl(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -291,9 +386,17 @@ def _merge_sorted_impl(a: jax.Array, b: jax.Array) -> jax.Array:
     ``searchsorted`` rank computation — the incremental alternative to a full
     rebuild + sort when new NEG keys arrive.  Returns the first P slots of
     the merged order, which hold every real key (each pair contributes at
-    most one key, so real keys across both inputs never exceed P)."""
-    P = a.shape[0]
-    sentinel = jnp.asarray(jnp.iinfo(a.dtype).max, a.dtype)
+    most one key, so real keys across both inputs never exceed P).  Two-word
+    (2, P) keys merge the same way by the two-word search."""
+    P = a.shape[-1]
+    sentinel = _sentinel_of(a)
+    if a.ndim == 2:
+        ia = jnp.arange(P, dtype=jnp.int32) + _search_wide(b, a, right=False)
+        ib = jnp.arange(P, dtype=jnp.int32) + _search_wide(a, b, right=True)
+        out = jnp.full((2, 2 * P), sentinel, a.dtype)
+        out = out.at[:, ia].set(a)
+        out = out.at[:, ib].set(b)
+        return out[:, :P]
     ia = jnp.arange(P, dtype=jnp.int32) + jnp.searchsorted(b, a, side="left")
     ib = jnp.arange(P, dtype=jnp.int32) + jnp.searchsorted(a, b, side="right")
     out = jnp.full((2 * P,), sentinel, a.dtype)
@@ -341,7 +444,7 @@ class SessionState:
     Invariants (DESIGN.md §8): ``roots`` are the canonical (min-vertex-id)
     connected components of the POS-labeled edges, and ``neg_keys`` is the
     sorted multiset of canonical root-pair keys of the NEG-labeled edges
-    under those roots (sentinel-padded to shape (P,)).  Both are therefore
+    under those roots (sentinel-padded to P slots).  Both are therefore
     bit-identical to a from-scratch rebuild from ``labels`` at any point —
     which holds even under noisy answer streams, because contradictory
     answers are rejected at the fold (DESIGN.md §9) rather than folded in.
@@ -360,7 +463,8 @@ class SessionState:
     labels: jax.Array     # (P,) int32 {UNKNOWN, NEG, POS}
     published: jax.Array  # (P,) bool — in-flight pairs
     roots: jax.Array      # (n_objects,) int32 union-find forest over POS edges
-    neg_keys: jax.Array   # (P,) sorted canonical keys of NEG edges
+    neg_keys: jax.Array   # (P,) sorted canonical keys of NEG edges, or
+    #                       (2, P) two-word keys where they do not fit one
     rounds: jax.Array     # () int32 answer-fold counter
     conflicts: jax.Array  # (P,) int32 rejected contradictory answers per pair
     priority: jax.Array   # (P,) f32 live labeling priority (lower = sooner)
@@ -392,7 +496,7 @@ def make_session_state(u, v, n_objects: int, pair_capacity: int = 0,
         labels=jnp.asarray(labels),
         published=jnp.zeros(p_cap, bool),
         roots=jnp.arange(n_cap, dtype=jnp.int32),
-        neg_keys=jnp.full((p_cap,), _key_sentinel(), _key_dtype()),
+        neg_keys=_empty_keys((p_cap,), n_cap),
         rounds=jnp.int32(0),
         conflicts=jnp.zeros(p_cap, jnp.int32),
         priority=jnp.arange(p_cap, dtype=jnp.float32),
@@ -411,7 +515,7 @@ def make_session_state_batch(U, V, labels0, n_objects: int) -> SessionState:
         published=jnp.zeros((B, P), bool),
         roots=jnp.broadcast_to(jnp.arange(n_objects, dtype=jnp.int32),
                                (B, n_objects)),
-        neg_keys=jnp.full((B, P), _key_sentinel(), _key_dtype()),
+        neg_keys=_empty_keys((B, P), int(n_objects)),
         rounds=jnp.zeros((B,), jnp.int32),
         conflicts=jnp.zeros((B, P), jnp.int32),
         priority=jnp.broadcast_to(jnp.arange(P, dtype=jnp.float32), (B, P)),
@@ -459,20 +563,20 @@ def _grow_impl(state: SessionState, pair_capacity: int, object_capacity: int
     bit-for-bit; new pair slots take the inert pre-labeled POS self-loop
     (0, 0) exactly as ``make_session_state`` pads them, new object ids join
     as isolated singletons, and the sorted neg-key index is re-encoded under
-    the enlarged object universe (``lo * n' + hi``).  The re-encoding is a
-    strictly monotone map on real keys (keys compare as (lo, hi) tuples for
-    any modulus > hi) and fixes the sentinel, so the array stays sorted with
-    no merge pass."""
+    the enlarged object universe (``lo * n' + hi``, or two words once the
+    keys no longer fit one).  The re-encoding is a strictly monotone map on
+    real keys (keys compare as (lo, hi) tuples for any modulus > hi) and
+    fixes the sentinel, so the array stays sorted with no merge pass."""
     P_old = state.u.shape[0]
     n_old = state.n_objects
-    kdt = state.neg_keys.dtype
-    sentinel = jnp.asarray(jnp.iinfo(kdt).max, kdt)
     pad_p = pair_capacity - P_old
     lo, hi, is_pad = _decompose_keys(state.neg_keys, n_old)
-    rekeyed = jnp.where(
-        is_pad, sentinel,
-        canonical_keys(lo, hi, object_capacity))
-    negk = jnp.concatenate([rekeyed, jnp.full((pad_p,), sentinel, kdt)])
+    rekeyed = canonical_keys(lo, hi, object_capacity)
+    sentinel = _sentinel_of(rekeyed)
+    rekeyed = jnp.where(_per_key(is_pad, rekeyed), sentinel, rekeyed)
+    negk = jnp.concatenate(
+        [rekeyed, jnp.full(rekeyed.shape[:-1] + (pad_p,), sentinel,
+                           rekeyed.dtype)], axis=-1)
     return SessionState(
         u=jnp.concatenate([state.u, jnp.zeros(pad_p, jnp.int32)]),
         v=jnp.concatenate([state.v, jnp.zeros(pad_p, jnp.int32)]),
@@ -518,11 +622,6 @@ def _check_grow(state: SessionState, pair_capacity: int,
         raise ValueError(
             f"session_grow cannot shrink object capacity "
             f"{state.n_objects} -> {object_capacity}")
-    if not pair_keys_fit(object_capacity):
-        raise ValueError(
-            f"growing to n_objects={object_capacity} overflows "
-            f"{pair_key_bits() + 1}-bit pair keys; enable jax_enable_x64 "
-            "for large object universes")
 
 
 def session_grow(state: SessionState, pair_capacity: int,
@@ -597,20 +696,18 @@ def _apply_fast(state: SessionState, updates: jax.Array, new: jax.Array,
     every incoming POS edge."""
     n = state.n_objects
     labels = jnp.where(new, updates, state.labels)
-    sentinel = jnp.asarray(jnp.iinfo(state.neg_keys.dtype).max,
-                           state.neg_keys.dtype)
+    sentinel = _sentinel_of(state.neg_keys)
     # re-key only when a union moved a root AND there are real keys to move
     # (an all-sentinel index — the common early-session case — needs no sort)
-    moved = jnp.any(roots != state.roots) & (state.neg_keys[0] != sentinel)
+    moved = jnp.any(roots != state.roots) & _has_keys(state.neg_keys)
     negk = jax.lax.cond(
         moved, lambda nk: _rekey_impl(nk, roots, n), lambda nk: nk,
         state.neg_keys)
-    fresh = jnp.where(neg_new,
-                      canonical_keys(roots[state.u], roots[state.v], n),
-                      sentinel)
+    fresh = canonical_keys(roots[state.u], roots[state.v], n)
+    fresh = jnp.where(_per_key(neg_new, fresh), fresh, sentinel)
     negk = jax.lax.cond(
         jnp.any(neg_new),
-        lambda nk: _merge_sorted_impl(nk, jnp.sort(fresh)),
+        lambda nk: _merge_sorted_impl(nk, _sort_keys(fresh)),
         lambda nk: nk, negk)
     return labels, roots, negk, jnp.zeros(new.shape, bool)
 
@@ -634,10 +731,10 @@ def _apply_sequential(state: SessionState, updates: jax.Array,
     n = state.n_objects
     P = state.u.shape[0]
     kdt = state.neg_keys.dtype
-    nn = jnp.asarray(n, kdt)
     sentinel = jnp.asarray(jnp.iinfo(kdt).max, kdt)
-    negw0 = jnp.concatenate([state.neg_keys,
-                             jnp.full((P,), sentinel, kdt)])
+    negw0 = jnp.concatenate(
+        [state.neg_keys,
+         jnp.full(state.neg_keys.shape[:-1] + (P,), sentinel, kdt)], axis=-1)
 
     def body(i, carry):
         labels, roots, negw, cmask = carry
@@ -645,10 +742,8 @@ def _apply_sequential(state: SessionState, updates: jax.Array,
         active = new[i]
         ru, rv = roots[state.u[i]], roots[state.v[i]]
         same = ru == rv
-        lo = jnp.minimum(ru, rv).astype(kdt)
-        hi = jnp.maximum(ru, rv).astype(kdt)
-        key = lo * nn + hi
-        neg_hit = jnp.any(negw == key) & ~same
+        key = canonical_keys(ru, rv, n)
+        neg_hit = jnp.any(_keys_equal(negw, key)) & ~same
         conflict = active & ((same & (upd == NEG)) | (neg_hit & (upd == POS)))
         accept = active & ~conflict
         acc_pos = accept & (upd == POS) & ~same  # same-root POS: no-op union
@@ -660,11 +755,10 @@ def _apply_sequential(state: SessionState, updates: jax.Array,
         # re-canonicalize the work keys under the post-union forest
         klo, khi, is_pad = _decompose_keys(negw, n)
         rlo, rhi = roots[klo], roots[khi]
-        rekeyed = (jnp.minimum(rlo, rhi).astype(kdt) * nn
-                   + jnp.maximum(rlo, rhi).astype(kdt))
-        negw = jnp.where(acc_pos & ~is_pad, rekeyed, negw)
+        rekeyed = canonical_keys(rlo, rhi, n)
+        negw = jnp.where(_per_key(acc_pos & ~is_pad, negw), rekeyed, negw)
         # an accepted NEG appends its key at the scratch slot for pair i
-        negw = negw.at[P + i].set(jnp.where(acc_neg, key, sentinel))
+        negw = negw.at[..., P + i].set(jnp.where(acc_neg, key, sentinel))
         cmask = cmask.at[i].set(conflict)
         return labels, roots, negw, cmask
 
@@ -674,7 +768,7 @@ def _apply_sequential(state: SessionState, updates: jax.Array,
     # keys are already canonical under the final roots; real keys never
     # exceed P (one per NEG-labeled pair), so the first P sorted slots hold
     # them all — bit-identical to a from-scratch rebuild
-    return labels, roots, jnp.sort(negw)[:P], cmask
+    return labels, roots, _sort_keys(negw)[..., :P], cmask
 
 
 def _screen_impl(state: SessionState, updates: jax.Array):
@@ -776,15 +870,12 @@ def _deduce_impl(state: SessionState) -> SessionState:
     new = (ded != UNKNOWN) & (state.labels == UNKNOWN) & ~state.published
     labels = jnp.where(new, ded, state.labels)
     neg_new = new & (ded == NEG)
-    sentinel = jnp.asarray(jnp.iinfo(state.neg_keys.dtype).max,
-                           state.neg_keys.dtype)
-    fresh = jnp.where(
-        neg_new,
-        canonical_keys(state.roots[state.u], state.roots[state.v], n),
-        sentinel)
+    sentinel = _sentinel_of(state.neg_keys)
+    fresh = canonical_keys(state.roots[state.u], state.roots[state.v], n)
+    fresh = jnp.where(_per_key(neg_new, fresh), fresh, sentinel)
     negk = jax.lax.cond(
         jnp.any(neg_new),
-        lambda nk: _merge_sorted_impl(nk, jnp.sort(fresh)),
+        lambda nk: _merge_sorted_impl(nk, _sort_keys(fresh)),
         lambda nk: nk, state.neg_keys)
     return dataclasses.replace(state, labels=labels, neg_keys=negk)
 
@@ -863,11 +954,9 @@ def _frontier_impl(state: SessionState) -> jax.Array:
     # deducible pairs instead of inserting the optimistic label.
     ded_now = _deduce_lookup_impl(state.roots, state.neg_keys, u, v, n)
     pub = state.published & unknown & (ded_now != NEG)
-    sentinel = jnp.asarray(jnp.iinfo(state.neg_keys.dtype).max,
-                           state.neg_keys.dtype)
     # sorted index ⇒ a real key, if any, sits at slot 0; the count of real
     # keys is invariant under re-keying, so one check covers every round
-    has_neg = state.neg_keys[0] != sentinel
+    has_neg = _has_keys(state.neg_keys)
     roots0 = _union_impl(state.roots, u, v, pub, n)
     negk0 = jax.lax.cond(
         jnp.any(pub) & has_neg,

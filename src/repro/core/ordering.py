@@ -64,8 +64,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .cluster_graph import ClusterGraph, UNKNOWN
-from .jax_graph import (SessionState, _decompose_keys, engine_dispatches,
-                        engine_jit)
+from .jax_graph import (SessionState, _decompose_keys, _key_starts,
+                        engine_dispatches, engine_jit)
 
 # Damping per unit of negative degree around the pair's clusters.  0.25 is a
 # power of two, so `1 + NEG_DAMP * k` is exact in f32 and the host/device
@@ -86,9 +86,7 @@ def _neg_degree_impl(state: SessionState) -> jax.Array:
     lo, hi, is_pad = _decompose_keys(state.neg_keys, n)
     # neg_keys is sorted, so duplicates (deduced NEGs) are adjacent: count
     # each distinct cluster-pair key once, matching ClusterGraph.neg sets
-    first = jnp.concatenate([
-        jnp.ones((1,), bool),
-        state.neg_keys[1:] != state.neg_keys[:-1]])
+    first = _key_starts(state.neg_keys)
     w = jnp.where(is_pad | ~first, 0.0, 1.0).astype(jnp.float32)
     return jnp.zeros((n,), jnp.float32).at[lo].add(w).at[hi].add(w)
 

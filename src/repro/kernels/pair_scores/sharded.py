@@ -14,6 +14,13 @@ Capacity is a hard contract: a device that finds more than ``capacity``
 local candidates reports the overflow in ``n_dropped`` (callers either
 raise, re-run with a higher threshold, or grow the buffer) — never a silent
 truncation.
+
+A device compacts its block with a stable argsort of the candidate mask,
+one row chunk of at most ``CHUNK_CELLS`` cells at a time, into one buffer
+in row-major order.  A block up to that size (a product join on one chip)
+is one chunk; a person-registry linkage puts 25,000 x 25,000 on each
+device of a 2x2 mesh, where one sort over the whole block would hold
+several block-sized sort buffers.
 """
 from __future__ import annotations
 
@@ -30,6 +37,60 @@ from repro import obs
 
 from .kernel import pair_scores as _kernel_call
 from .ops import l2_normalize
+
+
+# Most cells of a device block compacted by one argsort.
+CHUNK_CELLS = 1 << 26
+
+
+def _chunk_rows(n_loc: int, m_loc: int) -> int:
+    """Rows per compaction chunk of an (n_loc, m_loc) device block:
+    ``n_loc`` (one piece) up to ``CHUNK_CELLS`` cells."""
+    if n_loc * m_loc <= CHUNK_CELLS:
+        return n_loc
+    return max(1, CHUNK_CELLS // m_loc)
+
+
+def _compact_by_rows(s, mask, capacity: int, chunk_rows: int, i0=0, j0=0):
+    """The first ``capacity`` candidate cells (``mask``) of the block ``s``
+    in row-major order — row and column (offset by the block's origin
+    ``i0``, ``j0``), -1 past the candidates, and score — with the block's
+    candidate count: what one stable argsort of the whole mask gives, one
+    ``chunk_rows`` slice of rows at a time, with indices that stay within
+    a chunk.  Chunk k appends its first
+    ``capacity`` candidates at the running count (clamped to ``capacity``),
+    so later chunks overwrite the unfilled tail and anything past
+    ``capacity`` falls off the end."""
+    n_loc, m_loc = s.shape
+    R = chunk_rows
+    take_k = min(capacity, R * m_loc)
+    ragged = n_loc % R != 0
+
+    def put(buf, x, off):
+        return jax.lax.dynamic_update_slice(buf, x, (off,))
+
+    def chunk(k, carry):
+        rows, cols, sc, total = carry
+        r0 = k * R
+        a0 = jnp.minimum(r0, n_loc - R)   # the last chunk slides back ...
+        blk_s = jax.lax.dynamic_slice_in_dim(s, a0, R, axis=0)
+        blk_m = jax.lax.dynamic_slice_in_dim(mask, a0, R, axis=0)
+        if ragged:  # ... and leaves the rows of the chunk before it to it
+            blk_m = blk_m & (a0 + jnp.arange(R)[:, None] >= r0)
+        flat_m = blk_m.reshape(-1)
+        take = jnp.argsort(~flat_m, stable=True)[:take_k]
+        got = flat_m[take]
+        off = jnp.minimum(total, capacity)
+        rows = put(rows, jnp.where(got, i0 + a0 + take // m_loc, -1), off)
+        cols = put(cols, jnp.where(got, j0 + take % m_loc, -1), off)
+        sc = put(sc, jnp.where(got, blk_s.reshape(-1)[take], 0.0), off)
+        return rows, cols, sc, total + flat_m.sum(dtype=jnp.int32)
+
+    size = capacity + take_k
+    init = (jnp.full((size,), -1, jnp.int32), jnp.full((size,), -1, jnp.int32),
+            jnp.zeros((size,), jnp.float32), jnp.int32(0))
+    rows, cols, sc, total = jax.lax.fori_loop(0, -(-n_loc // R), chunk, init)
+    return rows[:capacity], cols[:capacity], sc[:capacity], total
 
 
 def _mesh_extents(mesh: Mesh):
@@ -95,6 +156,7 @@ def _sharded_candidates_jit(a, b, *, threshold: float, capacity: int,
     dd, dm = _mesh_extents(mesh)
     n_loc = a.shape[0] // dd
     m_loc = b.shape[0] // dm
+    chunk_rows = _chunk_rows(n_loc, m_loc)
 
     def body(a_loc, b_loc):
         # a_loc: (n_loc, D) on this data-rank; b_loc: (m_loc, D) on this
@@ -103,23 +165,10 @@ def _sharded_candidates_jit(a, b, *, threshold: float, capacity: int,
         j0 = jax.lax.axis_index("model") * m_loc
         s = _local_block_scores(a_loc, b_loc, threshold, interpret)
         mask = s >= threshold
-        flat_s = s.reshape(-1)
-        flat_m = mask.reshape(-1)
-        # stable compaction: candidate entries first, original order kept
-        order = jnp.argsort(~flat_m, stable=True)
-        take = order[:capacity]
-        got = flat_m[take]
-        rows = (i0 + take // m_loc).astype(jnp.int32)
-        cols = (j0 + take % m_loc).astype(jnp.int32)
-        n_cand = flat_m.sum().astype(jnp.int32)
-        dropped = jnp.maximum(n_cand - capacity, 0)
-        out = (
-            jnp.where(got, rows, -1)[None, None],
-            jnp.where(got, cols, -1)[None, None],
-            jnp.where(got, flat_s[take], 0.0)[None, None],
-            dropped[None, None],
-        )
-        return out
+        rows, cols, scores, n_cand = _compact_by_rows(
+            s, mask, capacity, chunk_rows, i0, j0)
+        return (rows[None, None], cols[None, None], scores[None, None],
+                jnp.maximum(n_cand - capacity, 0)[None, None])
 
     fn = jax.shard_map(
         body, mesh=mesh,
@@ -169,10 +218,11 @@ def sharded_candidates(
         rows, cols, scores, dropped = _sharded_candidates_jit(
             a, b, threshold=threshold, capacity=cap, mesh=mesh,
             interpret=interpret)
-        rows = obs.to_host(rows).reshape(-1)
-        cols = obs.to_host(cols).reshape(-1)
-        scores = obs.to_host(scores).reshape(-1)
-        dropped = obs.to_host(dropped)
+        with obs.span("join.machine.readback"):
+            rows = obs.to_host(rows).reshape(-1)
+            cols = obs.to_host(cols).reshape(-1)
+            scores = obs.to_host(scores).reshape(-1)
+            dropped = obs.to_host(dropped)
     keep = rows >= 0
     # padded rows/cols score 0 < threshold, so they can't appear as candidates
     return ShardedCandidates(

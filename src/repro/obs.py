@@ -3,8 +3,9 @@
 Counters (:class:`Counter`) are always on.  ``engine_dispatches`` counts
 the round engine's host->device dispatches (compiled-function launches
 plus host-array uploads); ``host_syncs`` counts the device->host reads of
-the served path, each made through :func:`to_host`.  Readers take the
-difference of two readings.
+the served path, each made through :func:`to_host`; ``wide_key_lanes``
+counts lanes opened with two-word pair keys (object universes past
+46,340 ids).  Readers take the difference of two readings.
 
 Spans are off by default: :func:`span` then returns one shared null context
 and records nothing, so the served path pays one flag check per span.
@@ -48,6 +49,7 @@ class Counter:
 
 engine_dispatches = Counter()
 host_syncs = Counter()
+wide_key_lanes = Counter()
 
 
 def to_host(x):

@@ -297,6 +297,16 @@ def _bucket(n: int, floor: int = 8) -> int:
     return next_pow2(n, floor)
 
 
+def _object_bucket(n_objects: int) -> int:
+    """Object capacity of a lane: the power-of-two bucket, except where
+    the bucket would need two-word pair keys and ``n_objects`` itself fits
+    one word — such a lane keeps one-word keys at its raw size."""
+    n_cap = _bucket(n_objects)
+    if not pair_keys_fit(n_cap) and pair_keys_fit(n_objects):
+        return n_objects
+    return n_cap
+
+
 def _stack_states(states: List[SessionState]) -> SessionState:
     engine_dispatches.add()  # device-side restack of the lane group
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *states)
@@ -782,12 +792,9 @@ class JoinService:
             ordered = req.pairs.take(perm)
             P = len(ordered)
             p_cap = _bucket(P)
-            n_cap = _bucket(ordered.n_objects)
-            # canonical pair keys are lo * n + hi; don't let bucketing push
-            # n_cap past the representable range when the raw size is still
-            # fine
+            n_cap = _object_bucket(ordered.n_objects)
             if not pair_keys_fit(n_cap):
-                n_cap = ordered.n_objects
+                obs.wide_key_lanes.add()
             state = make_session_state(ordered.u, ordered.v,
                                        ordered.n_objects, pair_capacity=p_cap,
                                        object_capacity=n_cap)
@@ -846,10 +853,9 @@ class JoinService:
 
     def _ingest(self, lane: _Lane, new_pairs: PairSet) -> None:
         """Fold an arrival epoch into a live lane: grow the device state to
-        the new capacity bucket (``pair_keys_fit`` re-checked — bucketing
-        must not push the object universe past the representable key range,
-        and a universe that no longer fits at all raises instead of
-        corrupting the neg-key index), claim padded slots for the new pairs,
+        the new capacity bucket (``_object_bucket``: a universe that grows
+        past one-word pair keys re-encodes its neg-key index in two words),
+        claim padded slots for the new pairs,
         and refresh the priority layout.  Published bits, gateway tickets,
         spend accounting, and every already-labeled pair carry over
         untouched — existing pair slots never move."""
@@ -864,12 +870,7 @@ class JoinService:
         p_cap = max(int(lane.state.u.shape[0]), _bucket(new_p))
         n_cap = lane.state.n_objects
         if lane.ordered.n_objects > n_cap:
-            n_cap = _bucket(lane.ordered.n_objects)
-            if not pair_keys_fit(n_cap):
-                # same clamp as lane open: bucketing must not overflow the
-                # key range when the raw size still fits; session_grow
-                # raises if even the raw size no longer does
-                n_cap = lane.ordered.n_objects
+            n_cap = _object_bucket(lane.ordered.n_objects)
         if (p_cap, n_cap) != (int(lane.state.u.shape[0]),
                               lane.state.n_objects):
             lane.state = session_grow(lane.state, p_cap, n_cap)

@@ -183,8 +183,9 @@ def test_session_deduce_matches_from_scratch_without_published(
 # ---------------------------------------------------------------------------
 def test_pair_key_guard_x64_off_boundary():
     """With x64 disabled (the test default) keys are int32: n = 46340 is the
-    last universe whose n*n fits below 2**31; 46341 must be rejected by both
-    the predicate and canonical_keys."""
+    last universe whose n*n fits below 2**31 and keeps one-word keys; from
+    46341 on, both the predicate and canonical_keys switch to two int32
+    words (lo root over hi root)."""
     import jax
     if jax.config.jax_enable_x64:
         pytest.skip("x64 enabled — int32 boundary not in effect")
@@ -193,10 +194,15 @@ def test_pair_key_guard_x64_off_boundary():
     assert n_ok * n_ok < 2 ** 31 <= n_bad * n_bad
     assert pair_keys_fit(n_ok)
     assert not pair_keys_fit(n_bad)
-    r = jnp.zeros(3, jnp.int32)
-    canonical_keys(r, r, n_ok)  # fine
-    with pytest.raises(ValueError, match="overflows"):
-        canonical_keys(r, r, n_bad)
+    ru = jnp.array([5, 46339, 0], jnp.int32)
+    rv = jnp.array([3, 46338, 0], jnp.int32)
+    one = np.asarray(canonical_keys(ru, rv, n_ok))
+    assert one.shape == (3,) and one.dtype == np.int32
+    np.testing.assert_array_equal(one, [3 * n_ok + 5,
+                                        46338 * n_ok + 46339, 0])
+    two = np.asarray(canonical_keys(ru, rv, n_bad))
+    assert two.shape == (2, 3) and two.dtype == np.int32
+    np.testing.assert_array_equal(two, [[3, 46338, 0], [5, 46339, 0]])
 
 
 # ---------------------------------------------------------------------------

@@ -225,3 +225,45 @@ def test_engine_programs_carry_stable_names():
     text = graph._session_fold_fast_batch_jit.lower(
         stacked, updates, keep_conflicts_published=False).as_text()
     assert "@jit_engine_fold_fast_batch" in text.splitlines()[0]
+
+
+def test_machine_readback_span_sits_under_the_score_span():
+    """``join.machine.readback`` covers the four reads of the per-device
+    candidate buffers, inside ``join.machine.score``."""
+    from repro.launch.mesh import make_host_mesh
+
+    rng = np.random.default_rng(0)
+    a = jnp.asarray(rng.normal(size=(24, 8)), jnp.float32)
+    b = jnp.asarray(rng.normal(size=(20, 8)), jnp.float32)
+    obs.enable()
+    svc = JoinService(lanes=1)
+    s0 = obs.host_syncs.count
+    svc.submit_embeddings(a, b, 0.3, make_host_mesh(1, 1),
+                          crowd=PerfectCrowd(),
+                          truth_fn=lambda r, c: r == c, impl="interpret")
+    assert obs.host_syncs.count - s0 == 4
+    spans = obs.spans()
+    by_id = _by_id(spans)
+    (read,) = [s for s in spans if s.name == "join.machine.readback"]
+    score = by_id[read.parent]
+    assert score.name == "join.machine.score"
+    assert by_id[score.parent].name == "join.machine"
+    assert score.start_ns <= read.start_ns <= read.end_ns <= score.end_ns
+
+
+@pytest.mark.parametrize("n_objects,wide", [(46340, 0), (46341, 1),
+                                            (100000, 1)])
+def test_wide_key_lanes_counts_lanes_opened_with_two_word_keys(n_objects,
+                                                               wide):
+    from repro.core.pairs import PairSet
+
+    ps = PairSet(np.array([0, 1, 0], np.int32),
+                 np.array([1, n_objects - 1, n_objects - 1], np.int32),
+                 np.array([0.9, 0.8, 0.7], np.float32),
+                 np.array([True, True, True]), n_objects=n_objects)
+    svc = JoinService(lanes=1)
+    rid = svc.submit(ps, PerfectCrowd())
+    w0 = obs.wide_key_lanes.count
+    res = svc.run()[rid]
+    assert obs.wide_key_lanes.count - w0 == wide
+    assert res.labels.all() and res.n_deduced == 1

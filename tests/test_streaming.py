@@ -87,8 +87,20 @@ def test_session_grow_rejects_shrink_and_key_overflow():
         session_grow(st_, 8, 4)
     import jax
     if not jax.config.jax_enable_x64:
-        with pytest.raises(ValueError, match="overflows"):
-            session_grow(st_, 8, 46341)  # 46341**2 >= 2**31
+        # 46341**2 >= 2**31: growing past one-word keys re-encodes the
+        # index in two words, equal to a state built at the larger size
+        upd = jnp.full((8,), UNKNOWN, jnp.int32).at[0].set(NEG)
+        grown, _ = session_fold_answers(st_, upd)
+        grown = session_grow(grown, 8, 46341)
+        fresh = make_session_state(u, v, 2, pair_capacity=8,
+                                   object_capacity=46341)
+        fresh, _ = session_fold_answers(fresh, upd)
+        assert grown.neg_keys.shape == (2, 8)
+        np.testing.assert_array_equal(np.asarray(grown.neg_keys)[:, 0],
+                                      [0, 1])
+        for f in ("labels", "roots", "neg_keys"):
+            np.testing.assert_array_equal(np.asarray(getattr(grown, f)),
+                                          np.asarray(getattr(fresh, f)))
 
 
 def _noisy_stream_parity(world_builder, seed: int, flip: float = 0.35):
@@ -405,8 +417,9 @@ def test_submit_embeddings_overflow_reports_post_growth_capacity(
 
 def test_pair_keys_refit_checked_after_growth():
     """Regression (DESIGN.md §11): an arrival pushing the object universe
-    past the representable pair-key range must raise at ingest — before the
-    grown neg-key index could silently wrap — not corrupt the session."""
+    past one-word pair keys must re-encode the grown lane's neg-key index
+    in two words at ingest — never let it wrap — and the stream must then
+    label exactly as a single-shot submit of the concatenated pairs."""
     import jax
 
     from repro.serve.join_service import JoinService
@@ -414,16 +427,24 @@ def test_pair_keys_refit_checked_after_growth():
     if jax.config.jax_enable_x64:
         pytest.skip("x64 enabled — int32 boundary not in effect")
     n0 = 46340  # last universe whose n*n fits below 2**31
-    ps1 = PairSet(np.array([0, 1], np.int32),
-                  np.array([n0 - 1, n0 - 2], np.int32),
-                  np.array([0.9, 0.8], np.float32),
-                  np.array([False, False]), n_objects=n0)
-    ps2 = PairSet(np.array([2], np.int32), np.array([46341], np.int32),
-                  np.array([0.7], np.float32), np.array([False]))
+    ps1 = PairSet(np.array([0, 1, 0], np.int32),
+                  np.array([n0 - 1, n0 - 2, 1], np.int32),
+                  np.array([0.9, 0.8, 0.75], np.float32),
+                  np.array([True, True, False]), n_objects=n0)
+    ps2 = PairSet(np.array([2, n0 - 1, n0 - 2], np.int32),
+                  np.array([46341, 1, 0], np.int32),
+                  np.array([0.7, 0.6, 0.5], np.float32),
+                  np.array([False, False, False]))
     svc = JoinService(lanes=1)
-    svc.submit_stream([ps1, ps2], PerfectCrowd())
-    with pytest.raises(ValueError, match="overflows.*pair keys"):
-        svc.run()
+    rid = svc.submit_stream([ps1, ps2], PerfectCrowd())
+    got = svc.run()[rid]
+    ref_svc = JoinService(lanes=1)
+    ref_rid = ref_svc.submit(ps1.concat(ps2), PerfectCrowd())
+    ref = ref_svc.run()[ref_rid]
+    np.testing.assert_array_equal(got.labels, ref.labels)
+    np.testing.assert_array_equal(got.crowdsourced, ref.crowdsourced)
+    assert got.round_sizes == ref.round_sizes
+    assert got.n_deduced > 0  # a two-word neg key did the deducing
 
 
 def test_streaming_embeddings_end_to_end(entity_embeddings):

@@ -105,6 +105,27 @@ def test_round_engine_batch_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis() is not None
 
 
+def test_round_engine_two_word_keys_compiles_for_v5e(one_chip):
+    """The fused round engine with two-word pair keys, as a lane past
+    46,340 objects runs it: 4 lanes of 4,096 pairs over 65,536 objects."""
+    from repro.core.jax_graph import (SessionState,
+                                      _session_run_rounds_batch_jit)
+    from repro.serve.join_service import JoinService
+
+    B, Pn, n = 4, 4096, 65536
+    i32 = lambda *s: _spec((B,) + s, jnp.int32, one_chip)
+    state = SessionState(
+        u=i32(Pn), v=i32(Pn), labels=i32(Pn),
+        published=_spec((B, Pn), jnp.bool_, one_chip), roots=i32(n),
+        neg_keys=i32(2, Pn), rounds=i32(), conflicts=i32(Pn),
+        priority=_spec((B, Pn), jnp.float32, one_chip), n_objects=n)
+    compiled = _session_run_rounds_batch_jit.lower(
+        state, i32(Pn), _spec((B, Pn), jnp.float32, one_chip),
+        _spec((B,), jnp.bool_, one_chip), i32(),
+        max_rounds=JoinService.FUSED_ROUNDS_PER_DISPATCH).compile()
+    assert compiled.memory_analysis() is not None
+
+
 def test_sharded_candidates_compiles_for_v5e_2x2(topo):
     """The mesh-sharded dense machine phase over the full 16384 x 16384
     benchmark corpus, rows over ``data`` and columns over ``model``."""
@@ -116,5 +137,24 @@ def test_sharded_candidates_compiles_for_v5e_2x2(topo):
               NamedSharding(mesh, P("model", None)))
     compiled = _sharded_candidates_jit.lower(
         a, b, threshold=0.9, capacity=1 << 22, mesh=mesh,
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sharded_candidates_row_chunks_compile_for_v5e_2x2(topo):
+    """A device block past ``CHUNK_CELLS`` (8,200 x 8,200 cells a chip of
+    a 16,400 x 16,400 grid at D = 300) compacts by row chunks; the 2x2
+    program compiles, with the Pallas kernel in it."""
+    from repro.kernels.pair_scores.sharded import (CHUNK_CELLS, _chunk_rows,
+                                                   _sharded_candidates_jit)
+
+    assert _chunk_rows(8200, 8200) < 8200 and 8200 * 8200 > CHUNK_CELLS
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    a = _spec((16400, 300), jnp.float32,
+              NamedSharding(mesh, P("data", None)))
+    b = _spec((16400, 300), jnp.float32,
+              NamedSharding(mesh, P("model", None)))
+    compiled = _sharded_candidates_jit.lower(
+        a, b, threshold=0.9, capacity=1 << 16, mesh=mesh,
         interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
