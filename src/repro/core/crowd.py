@@ -41,6 +41,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs import gateway_ballot_pairs, gateway_bulk_pairs
 from .cluster_graph import MATCH, NEG, NON_MATCH, POS
 from .pairs import PairSet
 
@@ -227,6 +228,31 @@ class Crowd:
         """
         return None
 
+    def ask_bulk(self, pairs: PairSet, indices: np.ndarray,
+                 answers: Optional[np.ndarray] = None
+                 ) -> Optional[Tuple[np.ndarray, int]]:
+        """Answer many pairs in one call, or ``None`` to be asked per pair.
+
+        Non-None only for a crowd whose every ballot is one vote by a fresh
+        worker, independent of ask order: the gateway then posts the batch
+        as arrays (DESIGN.md §8).  The call must leave the crowd as
+        ``len(indices)`` in-order :meth:`ask_ballot` calls would — the
+        question counter and the fresh-worker counter each advance by
+        ``len(indices)``, pair ``indices[j]`` answered by worker
+        ``first + j``.
+
+        Args:
+            pairs: the candidate pair set.
+            indices: int pair indices to label, in ask order.
+            answers: the crowd's :meth:`precomputed_answers` at
+                ``indices``, when the caller already holds them.
+
+        Returns:
+            ``(labels, first)`` — int32 POS/NEG labels aligned with
+            ``indices`` and the first worker id — or ``None``.
+        """
+        return None
+
     def reset(self) -> None:
         """Zero the question counter (and the fresh-worker id counter)."""
         self.n_asked = 0
@@ -369,6 +395,42 @@ class PerfectCrowd(Crowd):
             return None
         return np.where(np.asarray(pairs.truth, bool), POS, NEG
                         ).astype(np.int32)
+
+    def ask_bulk(self, pairs: PairSet, indices: np.ndarray,
+                 answers: Optional[np.ndarray] = None
+                 ) -> Optional[Tuple[np.ndarray, int]]:
+        """Truth labels of many pairs at once (see :meth:`Crowd.ask_bulk`).
+
+        ``None`` when a subclass overrides :meth:`ask`, :meth:`ask_votes`
+        or :meth:`ask_ballot` — its per-pair hook must keep being called —
+        or when there is no ground truth (the per-pair path raises).
+
+        Args:
+            pairs: the candidate pair set.
+            indices: int pair indices to label, in ask order.
+            answers: ``precomputed_answers(pairs)[indices]``, if held.
+
+        Returns:
+            ``(labels, first)`` or ``None``.
+        """
+        cls = type(self)
+        if (cls.ask is not PerfectCrowd.ask
+                or cls.ask_votes is not Crowd.ask_votes
+                or cls.ask_ballot is not Crowd.ask_ballot
+                or pairs.truth is None):
+            return None
+        if answers is None:
+            answers = np.where(np.asarray(pairs.truth, bool)[indices],
+                               POS, NEG)
+        labels = np.array(answers, np.int32)
+        if labels.shape != np.shape(indices):
+            raise ValueError(
+                f"answers of shape {labels.shape} do not align with "
+                f"indices of shape {np.shape(indices)}")
+        first = getattr(self, "_worker_seq", 0)
+        self._worker_seq = first + len(labels)
+        self.n_asked += len(labels)
+        return labels, first
 
 
 @register_crowd
@@ -1034,6 +1096,37 @@ class _Task:
     answers: List[Tuple[int, int, Tuple[int, ...], Tuple[int, ...]]]
     likelihood: float
 
+    @property
+    def n_answers(self) -> int:
+        return len(self.answers)
+
+
+@dataclasses.dataclass
+class _Block:
+    # One columnar post (immediate mode, deterministic crowd): the per-pair
+    # tasks it stands for, in order — pair indices[j], label labels[j],
+    # one vote by worker first_worker + j — built only when read.
+    rid: int
+    indices: np.ndarray
+    labels: np.ndarray
+    first_worker: int
+    likelihood: np.ndarray
+
+    @property
+    def n_answers(self) -> int:
+        return len(self.indices)
+
+    @property
+    def answers(self) -> List[Tuple[int, int, Tuple[int, ...],
+                                    Tuple[int, ...]]]:
+        w0 = self.first_worker
+        return [(i, lab, (lab,), (w0 + j,)) for j, (i, lab) in
+                enumerate(zip(self.indices.tolist(), self.labels.tolist()))]
+
+    def tasks(self) -> List[_Task]:
+        return [_Task(rid=self.rid, answers=[a], likelihood=lik)
+                for a, lik in zip(self.answers, self.likelihood.tolist())]
+
 
 class CrowdGateway:
     """Batched crowd transport with in-flight tracking (DESIGN.md §8).
@@ -1050,7 +1143,10 @@ class CrowdGateway:
       available on the next ``poll`` at simulated time 0.  This is the
       transport for the round-barrier serving path; the per-pair
       ``crowd.ask`` loop lives here, batched per post, instead of in the
-      service.
+      service.  A ``post`` whose crowd answers in bulk
+      (:meth:`Crowd.ask_bulk`) under majority aggregation skips that loop:
+      the batch is held as arrays, with the same ledger, counters,
+      seen workers and answers the loop would leave.
     * ``latency=LatencyModel`` — simulated asynchronous platform: a finite
       pool of ``latency.n_workers`` workers picks waiting tasks (uniformly at
       random, as AMT assigns — or lowest-likelihood-first when ``nf=True``,
@@ -1114,7 +1210,8 @@ class CrowdGateway:
         self._rng = latency.sampler() if latency is not None else None
         # waiting: posted, not yet picked up by a worker (immediate mode:
         # not yet polled).
-        self._waiting: List[_Task] = []
+        self._waiting: List = []   # _Task and _Block, in post order
+        self._block_extra = 0   # pairs in waiting blocks less their count
         # running: (t_done, seq, task) min-heap on t_done
         self._running: List[Tuple[float, int, _Task]] = []
         self._free_workers = latency.n_workers if latency is not None else 0
@@ -1125,6 +1222,9 @@ class CrowdGateway:
         # ids already seen per (rid, index)
         self._attempts: dict = {}
         self._seen: Dict[Tuple[int, int], set] = {}
+        # seen workers of columnar posts, per rid: (indices, first worker),
+        # folded into _seen when read
+        self._seen_bulk: Dict[int, List[Tuple[np.ndarray, int]]] = {}
         self.n_posted = 0
         self.n_answered = 0
         self.n_requeried = 0
@@ -1186,7 +1286,7 @@ class CrowdGateway:
     @property
     def in_flight(self) -> int:
         """Tasks posted but not yet answered (waiting + running)."""
-        return len(self._waiting) + len(self._running)
+        return len(self._waiting) + self._block_extra + len(self._running)
 
     @property
     def measured_disagreement(self) -> float:
@@ -1206,12 +1306,20 @@ class CrowdGateway:
         Returns:
             Sorted worker ids; empty for never-posted pairs.
         """
+        if rid in self._seen_bulk:
+            self._fold_seen(rid)
         return tuple(sorted(self._seen.get((rid, int(index)), ())))
+
+    def _fold_seen(self, rid: int) -> None:
+        for idx, first in self._seen_bulk.pop(rid):
+            for j, i in enumerate(idx.tolist()):
+                self._seen.setdefault((rid, i), set()).add(first + j)
 
     def _enqueue(self, rid: int, pairs: PairSet, indices, crowd: Crowd,
                  n_assignments: Optional[int] = None,
                  cents_per_assignment: float = 0.0) -> Tuple[int, ...]:
         indices = tuple(int(i) for i in indices)
+        gateway_ballot_pairs.add(len(indices))
         for i in indices:
             ballot = crowd.ask_ballot(
                 pairs, i, n_assignments,
@@ -1237,12 +1345,49 @@ class CrowdGateway:
             self._assign()
         return indices
 
+    def _enqueue_bulk(self, rid: int, pairs: PairSet, indices, crowd: Crowd,
+                      answers: Optional[np.ndarray],
+                      cents_per_assignment: float
+                      ) -> Optional[Tuple[int, ...]]:
+        """The per-pair loop's effects for one post, as arrays; ``None``
+        (nothing done) where the loop must run.  Each pair is one unanimous
+        vote by a fresh worker, so the ledger depends only on the indices."""
+        if self.latency is not None or self.worker_model is not None:
+            return None
+        idx = np.array(indices, np.int64)
+        if not len(idx):
+            return None
+        got = crowd.ask_bulk(pairs, idx, answers)
+        if got is None:
+            return None
+        labels, first = got
+        n = len(idx)
+        self._waiting.append(_Block(
+            rid=rid, indices=idx, labels=labels, first_worker=first,
+            likelihood=np.asarray(pairs.likelihood)[idx]))
+        self._block_extra += n - 1
+        self._seen_bulk.setdefault(rid, []).append((idx, first))
+        self.n_votes += n
+        self._assignments[rid] = self._assignments.get(rid, 0) + n
+        # n in-order additions, as the loop makes them (cumsum accumulates
+        # in order; a pairwise sum could differ in the last bit)
+        spend = np.full(n + 1, cents_per_assignment, np.float64)
+        spend[0] = self._spent_cents.get(rid, 0.0)
+        self._spent_cents[rid] = float(np.cumsum(spend)[-1])
+        self.n_posted += n
+        gateway_bulk_pairs.add(n)
+        return tuple(idx.tolist())
+
     def post(self, rid: int, pairs: PairSet, indices, crowd: Crowd,
-             cents_per_assignment: float = 0.0) -> CrowdTicket:
+             cents_per_assignment: float = 0.0,
+             answers: Optional[np.ndarray] = None) -> CrowdTicket:
         """Post a batch of pair indices; the crowd is asked per pair here
         (batched transport), answers surface later via ``poll``.  Each
         assignment bought is charged at ``cents_per_assignment`` against the
-        request's running spend (``spent_cents``).
+        request's running spend (``spent_cents``).  In immediate mode under
+        majority aggregation, a crowd that answers in bulk
+        (:meth:`Crowd.ask_bulk`) is asked once for the whole batch, which
+        is held as arrays; the ledger and the answers are the same.
 
         Args:
             rid: request id the batch belongs to.
@@ -1250,15 +1395,20 @@ class CrowdGateway:
             indices: pair indices to post.
             cents_per_assignment: billing rate for spend accounting.
             crowd: the :class:`Crowd` to ask.
+            answers: the crowd's ``precomputed_answers`` at ``indices``,
+                when the caller holds them.
 
         Returns:
             A :class:`CrowdTicket` over the posted indices.
         """
-        indices = self._enqueue(rid, pairs, indices, crowd,
-                                cents_per_assignment=cents_per_assignment)
+        posted = self._enqueue_bulk(rid, pairs, indices, crowd, answers,
+                                    cents_per_assignment)
+        if posted is None:
+            posted = self._enqueue(rid, pairs, indices, crowd,
+                                   cents_per_assignment=cents_per_assignment)
         tid = self._next_tid
         self._next_tid += 1
-        return CrowdTicket(tid=tid, rid=rid, indices=indices)
+        return CrowdTicket(tid=tid, rid=rid, indices=posted)
 
     def post_cluster(self, rid: int, pairs: PairSet, indices, crowd: Crowd,
                      cents: float = 0.0, n_assignments: int = 1,
@@ -1406,7 +1556,7 @@ class CrowdGateway:
             self._seq += 1
             self._free_workers -= 1
 
-    def poll(self) -> List[CrowdAnswer]:
+    def poll(self, collect: bool = True) -> List[CrowdAnswer]:
         """Surface completed answers.
 
         Immediate mode returns everything posted at simulated time 0.
@@ -1415,15 +1565,22 @@ class CrowdGateway:
         up waiting tasks).  A cluster task's decoded verdicts land together
         at its single completion time.
 
+        Args:
+            collect: ``False`` consumes and counts the answers without
+                building them, for a caller that does not read them.
+
         Returns:
-            A list of :class:`CrowdAnswer` (possibly empty).
+            A list of :class:`CrowdAnswer` (possibly empty; always empty
+            without ``collect``).
         """
         if self.latency is None:
+            n = sum(t.n_answers for t in self._waiting)
             out = [CrowdAnswer(t.rid, i, lab, self._now, votes, workers)
                    for t in self._waiting
-                   for i, lab, votes, workers in t.answers]
+                   for i, lab, votes, workers in t.answers] if collect else []
             self._waiting.clear()
-            self.n_answered += len(out)
+            self._block_extra = 0
+            self.n_answered += n
             return out
         if not self._running:
             return []
@@ -1437,17 +1594,21 @@ class CrowdGateway:
         self._now = max(self._now, t0)
         self._assign()
         self.n_answered += len(out)
-        return out
+        return out if collect else []
 
-    def drain(self) -> List[CrowdAnswer]:
+    def drain(self, collect: bool = True) -> List[CrowdAnswer]:
         """Poll until nothing is in flight (the round-barrier transport).
+
+        Args:
+            collect: ``False`` consumes the answers without building them
+                (see :meth:`poll`).
 
         Returns:
             Every outstanding :class:`CrowdAnswer`, completion order.
         """
-        out = list(self.poll())
+        out = self.poll(collect)
         while self.in_flight:
-            out.extend(self.poll())
+            out.extend(self.poll(collect))
         return out
 
     # -- persistence (DESIGN.md §16) ------------------------------------
@@ -1481,12 +1642,16 @@ class CrowdGateway:
         Returns:
             A dict of plain JSON types.
         """
+        for rid in list(self._seen_bulk):
+            self._fold_seen(rid)
+        waiting = [task for t in self._waiting
+                   for task in (t.tasks() if isinstance(t, _Block) else [t])]
         return {
             "now": float(self._now),
             "seq": int(self._seq),
             "next_tid": int(self._next_tid),
             "rng": (None if self._rng is None else _rng_to_state(self._rng)),
-            "waiting": [self._task_to_state(t) for t in self._waiting],
+            "waiting": [self._task_to_state(t) for t in waiting],
             "running": [[float(t), int(s), self._task_to_state(task)]
                         for t, s, task in self._running],
             "attempts": [[int(rid), int(i), int(n)]
@@ -1532,6 +1697,7 @@ class CrowdGateway:
         if state["rng"] is not None:
             self._rng = _rng_from_state(state["rng"])
         self._waiting = [self._task_from_state(d) for d in state["waiting"]]
+        self._block_extra = 0
         self._running = [(float(t), int(s), self._task_from_state(d))
                          for t, s, d in state["running"]]
         heapq.heapify(self._running)
@@ -1541,6 +1707,7 @@ class CrowdGateway:
                           for rid, i, n in state["attempts"]}
         self._seen = {(int(rid), int(i)): set(ws)
                       for rid, i, ws in state["seen"]}
+        self._seen_bulk = {}
         for k, v in state["counters"].items():
             setattr(self, k, int(v))
         self._cluster_pairs = {int(r): int(n)

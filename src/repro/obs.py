@@ -5,7 +5,9 @@ the round engine's host->device dispatches (compiled-function launches
 plus host-array uploads); ``host_syncs`` counts the device->host reads of
 the served path, each made through :func:`to_host`; ``wide_key_lanes``
 counts lanes opened with two-word pair keys (object universes past
-46,340 ids).  Readers take the difference of two readings.
+46,340 ids); ``gateway_bulk_pairs`` and ``gateway_ballot_pairs`` count
+the pairs the crowd gateway posts as arrays and through its per-pair
+ballot loop.  Readers take the difference of two readings.
 
 Spans are off by default: :func:`span` then returns one shared null context
 and records nothing, so the served path pays one flag check per span.
@@ -50,6 +52,8 @@ class Counter:
 engine_dispatches = Counter()
 host_syncs = Counter()
 wide_key_lanes = Counter()
+gateway_bulk_pairs = Counter()
+gateway_ballot_pairs = Counter()
 
 
 def to_host(x):

@@ -1326,12 +1326,14 @@ class JoinService:
                     if len(idx):
                         # replay the wave's gateway traffic: per-pair billing
                         # and ask bookkeeping are order-independent, so one
-                        # post covers the rounds just simulated
+                        # post covers the rounds just simulated (as arrays,
+                        # where the crowd answers in bulk)
                         lane.crowdsourced[idx] = True
                         with obs.span("join.gateway.post", lane.req.rid):
                             gateway.post(lane.req.rid, lane.ordered, idx,
                                          lane.req.crowd,
-                                         cents_per_assignment=lane.rate_cents)
+                                         cents_per_assignment=lane.rate_cents,
+                                         answers=lane.answers_host[idx])
                         progress = True
                     new = labels[b, :lane.p]
                     progress |= bool((new != lane.labels_host).any())
@@ -1344,9 +1346,10 @@ class JoinService:
                             stuck.append(lane.req.rid)
                         else:  # ROUNDS_RUNNING: wave continues next dispatch
                             running = True
-                # consume the replayed posts (immediate mode)
+                # consume the replayed posts (immediate mode); their
+                # answers are already folded on the device
                 with obs.span("join.gateway.drain"):
-                    gateway.drain()
+                    gateway.drain(collect=False)
                 if stuck:
                     raise RuntimeError(
                         "join engine stuck: no frontier and nothing deducible "

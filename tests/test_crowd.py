@@ -338,3 +338,154 @@ def test_cluster_disagreement_escalates_to_pair_ballots():
     # escalations billed at the pair rate; agreed pairs rode the task price
     assert gw.spent_cents(0) == pytest.approx(2.0 + 0.3 * len(escalated))
     assert gw.assignments_posted(0) == 2 + 3 * len(escalated)
+
+
+# ---------------------------------------------------------------------------
+# columnar posts (DESIGN.md §8): a deterministic crowd's batch held as arrays
+# must leave exactly what the per-pair ballot loop leaves
+# ---------------------------------------------------------------------------
+class _PerPair(PerfectCrowd):
+    """A PerfectCrowd with its ballot hook overridden, so the gateway asks
+    it pair by pair: the reference for the columnar path."""
+
+    calls = 0
+
+    def ask_ballot(self, *args, **kwargs):
+        self.calls += 1
+        return super().ask_ballot(*args, **kwargs)
+
+
+def _gateway_script(seed: int, rate: float, columnar: bool):
+    """Drive one gateway through random posts, per-pair posts, requeries,
+    polls, seen-worker reads and snapshots; return what was observed.
+    ``columnar`` gives the main crowd bulk answers; otherwise it is asked
+    per pair.  A second crowd is always asked per pair."""
+    from repro import obs
+
+    rng = np.random.default_rng(seed)
+    n = 48
+    truth = rng.random(n) < 0.4
+    pairs = PairSet(np.arange(n, dtype=np.int32),
+                    np.arange(n, 2 * n, dtype=np.int32),
+                    rng.random(n).astype(np.float32), truth, n_objects=2 * n)
+    answers = PerfectCrowd().precomputed_answers(pairs)
+    main = PerfectCrowd() if columnar else _PerPair()
+    other = _PerPair()
+    gw = CrowdGateway(max_requeries=2)
+    seen: list = []
+    bulk0 = obs.gateway_bulk_pairs.count
+
+    def look():
+        seen.append(("ledger", gw.in_flight, gw.n_posted, gw.n_votes,
+                     gw.n_minority_votes, main.state_dict(),
+                     other.state_dict(),
+                     [(gw.spent_cents(r).hex(), gw.assignments_posted(r))
+                      for r in range(3)]))
+
+    for _ in range(40):
+        op = rng.integers(7)
+        rid = int(rng.integers(3))
+        idx = rng.choice(n, size=int(rng.integers(0, 12)),
+                         replace=bool(rng.integers(2)))
+        if op <= 1:
+            t = gw.post(rid, pairs, idx, main, cents_per_assignment=rate,
+                        answers=answers[idx] if op == 1 else None)
+            seen.append(("post", t))
+        elif op == 2:
+            seen.append(("post", gw.post(rid, pairs, list(idx), other,
+                                         cents_per_assignment=rate)))
+        elif op == 3:
+            seen.append(("requery", gw.requery(rid, pairs, idx, main,
+                                               cents_per_assignment=rate)))
+        elif op == 4:
+            seen.append(("poll", gw.poll()))
+        elif op == 5:
+            seen.append(("seen", [gw.seen_workers(rid, int(i))
+                                  for i in idx]))
+        else:
+            seen.append(("state", gw.state_dict()))
+        look()
+    # a snapshot with posts in flight restores to the same answers
+    back = CrowdGateway(max_requeries=2)
+    back.load_state_dict(gw.state_dict())
+    seen.append(("restored", back.drain(), back.state_dict()))
+    seen.append(("drain", gw.drain(), gw.state_dict()))
+    look()
+    return seen, obs.gateway_bulk_pairs.count - bulk0
+
+
+@pytest.mark.parametrize("rate", [0.1, 2.0])
+@pytest.mark.parametrize("seed", range(4))
+def test_columnar_post_matches_per_pair_loop(seed, rate):
+    got, n_bulk = _gateway_script(seed, rate, columnar=True)
+    want, n_bulk_ref = _gateway_script(seed, rate, columnar=False)
+    assert n_bulk > 0 and n_bulk_ref == 0  # the two paths were both taken
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g == w
+
+
+def test_columnar_spend_is_in_order_addition():
+    """0.1 has no exact binary form: n in-order additions differ from
+    ``0.1 * n`` and from a pairwise sum; the ledger keeps the loop's."""
+    pairs = _truth_pairs(1000)
+    gw = CrowdGateway()
+    gw.post(0, pairs, np.arange(1000), PerfectCrowd(),
+            cents_per_assignment=0.1)
+    ref = 0.0
+    for _ in range(1000):
+        ref += 0.1
+    assert gw.spent_cents(0).hex() == ref.hex()
+    assert ref != 0.1 * 1000
+
+
+@pytest.mark.parametrize("hook", ["ask", "ask_votes", "ask_ballot"])
+def test_overridden_ballot_hooks_keep_the_per_pair_loop(hook):
+    from repro import obs
+
+    calls = []
+
+    def spy(self, pairs, i, *args, **kwargs):
+        calls.append(i)
+        return getattr(PerfectCrowd, hook)(self, pairs, i, *args, **kwargs)
+
+    crowd = type("Spy", (PerfectCrowd,), {hook: spy})()
+    b0, p0 = obs.gateway_bulk_pairs.count, obs.gateway_ballot_pairs.count
+    gw = CrowdGateway()
+    gw.post(0, _truth_pairs(5), [4, 0, 2], crowd)
+    assert calls == [4, 0, 2]
+    assert obs.gateway_bulk_pairs.count == b0
+    assert obs.gateway_ballot_pairs.count == p0 + 3
+    assert [a.index for a in gw.drain()] == [4, 0, 2]
+
+
+@pytest.mark.parametrize("gateway", [
+    lambda: CrowdGateway(latency=LatencyModel(n_workers=3, seed=1)),
+    lambda: CrowdGateway(aggregation="em"),
+], ids=["latency", "em"])
+def test_columnar_post_needs_immediate_majority(gateway):
+    from repro import obs
+
+    b0 = obs.gateway_bulk_pairs.count
+    gw = gateway()
+    crowd = PerfectCrowd()
+    gw.post(0, _truth_pairs(6), range(6), crowd, cents_per_assignment=2.0)
+    assert sorted(a.index for a in gw.drain()) == list(range(6))
+    assert obs.gateway_bulk_pairs.count == b0 and crowd.n_asked == 6
+    assert gw.spent_cents(0) == 12.0
+
+
+def test_columnar_answers_must_align_with_indices():
+    with pytest.raises(ValueError, match="align"):
+        CrowdGateway().post(0, _truth_pairs(4), [0, 1, 2], PerfectCrowd(),
+                            answers=np.array([POS, NEG], np.int32))
+
+
+def test_drain_without_collect_consumes_and_counts():
+    gw = CrowdGateway()
+    pairs = _truth_pairs(5)
+    gw.post(0, pairs, [0, 1, 2], PerfectCrowd())
+    gw.post(1, pairs, [3], _PerPair())
+    assert gw.in_flight == 4
+    assert gw.drain(collect=False) == []
+    assert gw.in_flight == 0 and gw.n_answered == 4 and gw.poll() == []

@@ -185,6 +185,39 @@ def test_join_service_matches_single_session(crowd_factory):
         assert got.fold_rounds == got.n_rounds
 
 
+def test_fused_replay_posts_as_arrays_with_the_per_pair_ledger():
+    """The fused path replays a PerfectCrowd's waves through the gateway's
+    columnar post; a crowd whose ballot hook is overridden is asked pair by
+    pair.  Both serve the same labels, rounds, spend (to the bit) and
+    question counts."""
+    from repro import obs
+    from repro.serve.join_service import JoinService
+
+    class PerPair(PerfectCrowd):
+        def ask_ballot(self, *args, **kwargs):
+            return super().ask_ballot(*args, **kwargs)
+
+    def serve(crowd_cls):
+        pairsets = _session_pairsets(11)
+        svc = JoinService(lanes=2)
+        crowds = [crowd_cls() for _ in pairsets]
+        rids = [svc.submit(ps, c) for ps, c in zip(pairsets, crowds)]
+        b0 = obs.gateway_bulk_pairs.count
+        res = svc.run()
+        return ([res[r] for r in rids], [c.n_asked for c in crowds],
+                obs.gateway_bulk_pairs.count - b0)
+
+    got, asked, bulk = serve(PerfectCrowd)
+    want, asked_ref, bulk_ref = serve(PerPair)
+    assert bulk == sum(int(r.crowdsourced.sum()) for r in got) > 0
+    assert bulk_ref == 0 and asked == asked_ref
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g.labels, w.labels)
+        np.testing.assert_array_equal(g.crowdsourced, w.crowdsourced)
+        assert g.round_sizes == w.round_sizes
+        assert g.n_spent_cents.hex() == w.n_spent_cents.hex()
+
+
 def test_join_service_streaming_submit_between_runs():
     from repro.serve.join_service import JoinService
 

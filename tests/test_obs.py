@@ -267,3 +267,41 @@ def test_wide_key_lanes_counts_lanes_opened_with_two_word_keys(n_objects,
     res = svc.run()[rid]
     assert obs.wide_key_lanes.count - w0 == wide
     assert res.labels.all() and res.n_deduced == 1
+
+
+@pytest.mark.parametrize("case", ["perfect_fused", "noisy", "latency"])
+def test_gateway_counters_say_which_path_posted(case):
+    """A fused PerfectCrowd run replays every crowdsourced pair through the
+    gateway's columnar post, with the parent's dispatches and host reads; a
+    NoisyCrowd run and a latency-mode run ask pair by pair."""
+    from repro.core import LatencyModel, NoisyCrowd
+
+    pss = make_session_pairsets(2, seed=5, n_objects=(10, 16),
+                                n_pairs=(20, 31), n_entities=4)
+    if case == "perfect_fused":
+        crowds, kw = [PerfectCrowd(), PerfectCrowd()], {}
+    elif case == "noisy":
+        crowds = [NoisyCrowd(error_rate=0.1, seed=s) for s in (1, 2)]
+        kw = {}
+    else:
+        crowds = [PerfectCrowd(), PerfectCrowd()]
+        kw = {"latency": LatencyModel(n_workers=4, seed=3)}
+    svc = JoinService(lanes=2, **kw)
+    rids = [svc.submit(ps, c) for ps, c in zip(pss, crowds)]
+    obs.enable()
+    b0, p0 = obs.gateway_bulk_pairs.count, obs.gateway_ballot_pairs.count
+    d0, s0 = obs.engine_dispatches.count, obs.host_syncs.count
+    res = svc.run()
+    bulk = obs.gateway_bulk_pairs.count - b0
+    ballot = obs.gateway_ballot_pairs.count - p0
+    replayed = sum(int(res[r].crowdsourced.sum()) for r in rids)
+    asked = sum(c.n_asked for c in crowds)
+    assert replayed > 0 and asked == bulk + ballot
+    if case == "perfect_fused":
+        assert bulk == replayed == asked and ballot == 0
+        n_dispatch = sum(s.name == "join.engine_dispatch"
+                         for s in obs.spans())
+        assert obs.host_syncs.count - s0 == 5 * n_dispatch + 2 * 2
+        assert obs.engine_dispatches.count - d0 == 9
+    else:
+        assert bulk == 0 and ballot == asked >= replayed
