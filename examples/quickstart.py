@@ -6,7 +6,7 @@ from repro.core import NoisyCrowd, PerfectCrowd, crowdsourced_join
 from repro.data.entities import make_paper_dataset
 
 # 1) machine phase: candidate pairs + matching likelihoods (synthetic
-#    Cora-like dataset; see examples/crowdsourced_join.py for the LM scorer)
+#    Cora-like dataset; see examples/crowdsourced_join.py for the kernel)
 ds = make_paper_dataset()
 candidates = ds.pairs.above(0.3)
 print(f"dataset: {ds.n_objects} records, {len(candidates)} candidate pairs")

@@ -1,7 +1,7 @@
 """End-to-end crowdsourced join operator.
 
 Composes the full hybrid human-machine pipeline of the paper: candidate pairs
-(from the machine phase — a likelihood model / LM scorer / generative sim) →
+(from the machine phase — the pair-scores kernel or a generative sim) →
 sorting component → labeling component (sequential / parallel / JAX engine)
 → join result + quality + cost accounting.
 """

@@ -2,8 +2,8 @@
 
 A ``PairSet`` is a struct-of-arrays over the machine-generated candidate pairs:
 object ids ``u``/``v``, the machine ``likelihood`` that each pair matches
-(§4.2, from the similarity methods of [25] or from an LM scorer), and — when
-known, for simulation — the ground-truth labels.
+(§4.2, from the similarity methods of [25] or the pair-scores kernel), and —
+when known, for simulation — the ground-truth labels.
 """
 from __future__ import annotations
 

@@ -135,12 +135,18 @@ def expected_crowdsourced(pairs: PairSet, order: np.ndarray) -> float:
     p = pairs.likelihood.astype(np.float64)
     total_prob = 0.0
     exp_count = 0.0
+    counts = []
     for world in itertools.product([True, False], repeat=n):
         if not _consistent(pairs.n_objects, pairs.u, pairs.v, world):
             continue
         prob = 1.0
         for i in range(n):
             prob *= p[i] if world[i] else (1.0 - p[i])
+        c = count_crowdsourced(pairs, order, world)
+        counts.append(c)
         total_prob += prob
-        exp_count += prob * count_crowdsourced(pairs, order, world)
-    return exp_count / total_prob
+        exp_count += prob * c
+    # a weighted mean lies within its values; keep float rounding of the
+    # two sums from pushing it out (a star crowdsources every pair in
+    # every world, so E[C] is exactly its pair count)
+    return min(max(exp_count / total_prob, min(counts)), max(counts))

@@ -9,8 +9,7 @@ cluster-size distributions, plus a machine-likelihood model (Beta mixtures —
 the likelihood a similarity function of [25] would emit) calibrated so that
 candidate-set sizes across thresholds 0.1–0.5 land in the paper's ballpark.
 
-Records also carry synthetic strings (corrupted canonical names) so the
-end-to-end LM-scorer example has real text to embed.
+Records also carry synthetic strings (corrupted canonical names).
 """
 from __future__ import annotations
 

@@ -1,45 +1,19 @@
-"""Serving launcher: batched generation with the ServeEngine, or the
-durable join service with kill/restore recovery (DESIGN.md §16).
+"""Serving launcher: the durable join service with kill/restore recovery
+(DESIGN.md §16).
 
-    # generation lanes (the default mode)
-    PYTHONPATH=src python -m repro.launch.serve --arch paper-scorer --requests 8
-
-    # durable join serving: run with checkpoints, kill after N commits...
-    PYTHONPATH=src python -m repro.launch.serve --mode join \
+    # run with checkpoints, kill after N commits...
+    PYTHONPATH=src python -m repro.launch.serve \
         --checkpoint-dir /tmp/join_ckpt --kill-after 2
 
     # ...then resume from the latest checkpoint and finish
-    PYTHONPATH=src python -m repro.launch.serve --mode join \
+    PYTHONPATH=src python -m repro.launch.serve \
         --checkpoint-dir /tmp/join_ckpt --resume
 """
 from __future__ import annotations
 
 import argparse
 
-import jax
 import numpy as np
-
-
-def _generate(args) -> None:
-    from repro.configs import get
-    from repro.models.model import init_params
-    from repro.serve.engine import Request, ServeEngine
-
-    cfg = get(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    engine = ServeEngine(cfg, params, batch_lanes=args.lanes, max_len=256)
-    rng = np.random.default_rng(0)
-    reqs = [Request(rid=i,
-                    prompt=rng.integers(2, cfg.vocab, size=rng.integers(4, 24)
-                                        ).astype(np.int32),
-                    max_new_tokens=args.max_new)
-            for i in range(args.requests)]
-    out = engine.generate(reqs)
-    for rid in sorted(out):
-        print(f"req {rid}: {out[rid][:12]}{'...' if len(out[rid]) > 12 else ''}")
-    print(f"[serve] {len(out)} requests completed")
 
 
 def _join_workload(seed: int, n: int = 48, p: int = 160):
@@ -56,10 +30,11 @@ def _join_workload(seed: int, n: int = 48, p: int = 160):
                    truth=truth, n_objects=n)
 
 
-def _join(args) -> None:
+def _join(args):
     """Durable join serving (DESIGN.md §16): fresh run with checkpoints —
     optionally killed after N commits — or `--resume` from the latest
-    checkpoint in `--checkpoint-dir`."""
+    checkpoint in `--checkpoint-dir`.  Returns the results by request id,
+    or None when killed."""
     from repro.core.crowd import NoisyCrowd
     from repro.serve.join_service import JoinService, ServiceKilled
 
@@ -83,7 +58,7 @@ def _join(args) -> None:
     except ServiceKilled as e:
         print(f"[serve] killed: {e}")
         print("[serve] re-run with --resume to recover")
-        return
+        return None
     for rid in sorted(results):
         res = results[rid]
         f = (f", F={res.quality.f_measure:.3f}"
@@ -92,33 +67,25 @@ def _join(args) -> None:
               f"{res.n_crowdsourced} crowdsourced, "
               f"{res.n_spent_cents:.1f} cents{f}")
     print(f"[serve] {len(results)} join requests completed")
+    return results
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", choices=("generate", "join"),
-                    default="generate")
-    ap.add_argument("--arch", default="paper-scorer")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--lanes", type=int, default=4)
-    ap.add_argument("--max-new", type=int, default=16)
-    ap.add_argument("--reduced", action="store_true", default=True)
-    ap.add_argument("--full", dest="reduced", action="store_false")
-    # join-mode recovery controls (DESIGN.md §16)
+    # recovery controls (DESIGN.md §16)
     ap.add_argument("--checkpoint-dir", default=None,
-                    help="join mode: checkpoint serving state here")
+                    help="checkpoint serving state here")
     ap.add_argument("--checkpoint-every", type=int, default=1)
     ap.add_argument("--kill-after", type=int, default=0,
-                    help="join mode: die after N checkpoint commits")
+                    help="die after N checkpoint commits")
     ap.add_argument("--resume", action="store_true",
-                    help="join mode: restore from --checkpoint-dir")
-    args = ap.parse_args()
-    if args.mode == "join":
-        if (args.resume or args.kill_after) and not args.checkpoint_dir:
-            ap.error("--resume/--kill-after require --checkpoint-dir")
-        _join(args)
-    else:
-        _generate(args)
+                    help="restore from --checkpoint-dir")
+    args = ap.parse_args(argv)
+    if (args.resume or args.kill_after) and not args.checkpoint_dir:
+        ap.error("--resume/--kill-after require --checkpoint-dir")
+    return _join(args)
 
 
 if __name__ == "__main__":

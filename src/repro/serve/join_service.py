@@ -1,7 +1,7 @@
 """JoinService — streaming join requests over the persistent session engine.
 
-The serving counterpart of ``ServeEngine`` for the paper's pipeline
-(DESIGN.md §7, §8): join requests queue up, get packed into a fixed number of
+The serving layer of the paper's pipeline (DESIGN.md §7, §8): join
+requests queue up, get packed into a fixed number of
 session *lanes*, and every lane carries a device-resident
 :class:`~repro.core.jax_graph.SessionState` that is packed **once** at lane
 open and updated incrementally — no per-round re-pack, no from-scratch
@@ -15,8 +15,7 @@ Two serving disciplines over the same state machinery:
   is one batched frontier dispatch over bucket-grouped stacked lane states,
   one gateway post per lane, a full gateway drain, and one fused
   apply+deduce dispatch.  A lane whose session fully labels is finalized and
-  refilled from the queue mid-wave — the same continuous lane-refill design
-  ``ServeEngine`` uses for decode lanes.
+  refilled from the queue mid-wave (continuous lane refill).
 * **Asynchronous ID/NF** (``async_mode=True``): the event-driven regime of
   §5.2, lifted from ``core/parallel.py``'s host simulator into serving.  A
   lane folds answers the moment the gateway delivers them; a returned
@@ -461,7 +460,7 @@ class JoinService:
         self.cache_path = cache_path
         self._cache_fps: Dict[int, Tuple[List[str], List[str]]] = {}
         # durable serving state (DESIGN.md §16): periodic checkpoints of
-        # lanes + gateway + ledgers through train/checkpoint.py; restore()
+        # lanes + gateway + ledgers through serve/checkpoint.py; restore()
         # rebuilds the service from the latest one.  _crash_after_checkpoints
         # is the deterministic kill switch the recovery tests/bench use.
         self.checkpoint_dir = checkpoint_dir
@@ -469,7 +468,7 @@ class JoinService:
         self.checkpoint_keep = checkpoint_keep
         self._ckpt = None
         if checkpoint_dir is not None:
-            from repro.train.checkpoint import CheckpointManager
+            from repro.serve.checkpoint import CheckpointManager
             self._ckpt = CheckpointManager(checkpoint_dir,
                                            keep=checkpoint_keep)
         self._ckpt_step = 0

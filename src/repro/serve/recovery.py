@@ -4,7 +4,7 @@
 ``SessionState`` pytrees pulled to host), the admitted queue, finished
 results, pending arrival epochs, the gateway's in-flight tickets and spend
 ledgers, and the admission-envelope counters — into the ``(tree, sidecar)``
-pair the :class:`~repro.train.checkpoint.CheckpointManager` persists
+pair the :class:`~repro.serve.checkpoint.CheckpointManager` persists
 atomically: arrays ride the npz path, everything JSON rides the sidecar.
 
 ``restore_service`` inverts it: rebuild the service from the saved
@@ -350,7 +350,7 @@ def restore_service(cls, checkpoint_dir: str, step: Optional[int] = None,
     """
     from repro.core.crowd import CostModel, LatencyModel
     from repro.serve.join_service import AdmissionPolicy
-    from repro.train.checkpoint import CheckpointManager
+    from repro.serve.checkpoint import CheckpointManager
 
     mgr = CheckpointManager(checkpoint_dir)
     got_step, tree, _ = mgr.restore(step)

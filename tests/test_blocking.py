@@ -451,3 +451,24 @@ def test_property_streaming_union_matches_batch(seed, cut):
     batch = blocked_candidates(a, b, TAU, cfg, normalize=False)
     assert union == _pair_set(batch.rows, batch.cols)
     _assert_parity(batch, a, b, TAU, floor=0.9)
+
+
+# ---------------------------------------------------------------------------
+# the wa.blocked deployment's blocker: D = 300, tau = 0.9, a 0.99 recall
+# floor at six bits a table, 16 x 128 tiles, 256 tiles a kernel call
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_blocked_at_walmart_amazon_config_matches_dense_oracle(seed):
+    rng = np.random.default_rng(seed)
+    cents = rng.normal(size=(60, 300))
+    noise = lambda n: rng.uniform(0.15, 0.45, size=(n, 1)) * rng.normal(
+        size=(n, 300))
+    mk = lambda n: np.asarray(l2_normalize(jnp.asarray(
+        (cents[rng.integers(0, 60, n)] + noise(n)).astype(np.float32))))
+    a, b = mk(160), mk(420)
+    cfg = BlockingConfig.for_recall(0.99, 0.9, n_bits=6, bn=16, bm=128,
+                                    tiles_per_call=256)
+    cand = blocked_candidates(a, b, 0.9, cfg, normalize=False)
+    dense, blocked = _assert_parity(cand, a, b, 0.9, floor=0.99)
+    assert len(dense) > 100 and cand.n_dropped == 0
+    assert cand.cells_scored < cand.dense_cells == 160 * 420

@@ -1,10 +1,7 @@
-"""Synthetic datasets + token pipeline properties."""
+"""Synthetic datasets: calibration and determinism."""
 import numpy as np
-import pytest
 
 from repro.data.entities import make_paper_dataset, make_product_dataset
-from repro.data.tokens import (TokenPipeline, corpus_from_records,
-                               hash_tokenize, pack_documents)
 
 
 def test_paper_dataset_calibration(paper_ds):
@@ -33,29 +30,3 @@ def test_dataset_determinism():
     c = make_paper_dataset(seed=1)
     assert len(c.pairs) != len(a.pairs) or \
         not np.array_equal(a.pairs.likelihood, c.pairs.likelihood)
-
-
-def test_tokenizer_deterministic_and_bounded():
-    t1 = hash_tokenize("iPad 2nd Gen", 1000, 8)
-    t2 = hash_tokenize("iPad 2nd Gen", 1000, 8)
-    np.testing.assert_array_equal(t1, t2)
-    assert (t1 >= 2).all() and (t1 < 1000).all()
-
-
-def test_packing_shapes():
-    docs = [np.arange(2, 12, dtype=np.int32)] * 7
-    rows = pack_documents(docs, seq_len=16)
-    assert rows.shape[1] == 16
-    assert rows.dtype == np.int32
-
-
-def test_pipeline_epochs_cover_data():
-    rows = np.arange(32 * 32, dtype=np.int32).reshape(32, 32)  # unique rows
-    pipe = TokenPipeline(rows, global_batch=4, seed=1)
-    seen = set()
-    for s in range(pipe.steps_per_epoch):
-        b = pipe.batch_at(s)["tokens"]
-        for r in b:
-            seen.add(r.tobytes())
-    # one epoch touches distinct rows (no repeats within epoch)
-    assert len(seen) == pipe.steps_per_epoch * 4

@@ -6,8 +6,6 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels.decode_attention.ops import decode_attention
-from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.pair_scores.kernel import pair_scores_compact
 from repro.kernels.pair_scores.ops import l2_normalize, pair_scores
 from repro.kernels.pair_scores.ref import candidate_diff, candidates_ref
@@ -172,64 +170,96 @@ def test_pair_scores_compact_all_padding_tiles():
 
 
 # ---------------------------------------------------------------------------
-# flash attention
+# the embedding cells' width: D = 300 (DeepMatcher's fastText vectors, the
+# walmart_amazon and febrl_link deployments), on shapes no tile divides
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("B,S,H,K,d", [
-    (2, 256, 4, 4, 64),     # MHA
-    (1, 512, 8, 2, 128),    # GQA 4:1, d=128
-    (2, 384, 6, 3, 64),     # GQA 2:1, non-pow2 S
-    (1, 128, 2, 1, 128),    # MQA
-])
+D_CELLS = 300
+
+
+def _clustered(rng, n, cents, noise=0.8):
+    """Rows around shared entity centroids: cosine near 0.6 within an
+    entity, near 0 across, so the threshold cuts through real structure."""
+    ids = rng.integers(0, len(cents), n)
+    return cents[ids] + noise * rng.normal(size=(n, cents.shape[1]))
+
+
+@pytest.mark.parametrize("N,M", [(300, 500), (257, 1000), (1024, 129)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_sweep(B, S, H, K, d, dtype):
-    q = jnp.asarray(RNG.normal(size=(B, S, H, d)), dtype)
-    k = jnp.asarray(RNG.normal(size=(B, S, K, d)), dtype)
-    v = jnp.asarray(RNG.normal(size=(B, S, K, d)), dtype)
-    o = flash_attention(q, k, v, impl="interpret")
-    r = flash_attention(q, k, v, impl="ref")
-    tol = 2e-5 if dtype == jnp.float32 else 3e-2
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(r, np.float32), atol=tol)
+def test_pair_scores_at_cell_width_matches_oracle(N, M, dtype):
+    """The dense kernel at D = 300 against the oracle: padded to 256-row
+    tiles on both sides, scores within a few ulps (bf16 inputs are scored
+    in f32, so they keep the f32 band) and per-row counts exact."""
+    rng = np.random.default_rng(N * 7 + M)
+    cents = rng.normal(size=(64, D_CELLS))
+    a = jnp.asarray(_clustered(rng, N, cents), dtype)
+    b = jnp.asarray(_clustered(rng, M, cents), dtype)
+    s, c = pair_scores(a, b, 0.5, impl="interpret")
+    sr, cr = pair_scores(a, b, 0.5, impl="ref")
+    assert s.shape == (N, M) and c.shape == (N, 1)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(sr), atol=BAND)
+    np.testing.assert_array_equal(np.asarray(c), np.asarray(cr))
+    assert int(np.asarray(c).sum()) > N // 8   # the threshold bites
 
 
-def test_flash_attention_block_shape_invariance():
-    q = jnp.asarray(RNG.normal(size=(1, 256, 4, 64)), jnp.float32)
-    k = jnp.asarray(RNG.normal(size=(1, 256, 2, 64)), jnp.float32)
-    v = jnp.asarray(RNG.normal(size=(1, 256, 2, 64)), jnp.float32)
-    o1 = flash_attention(q, k, v, impl="interpret", bq=128, bk=128)
-    o2 = flash_attention(q, k, v, impl="interpret", bq=64, bk=256)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
-
-
-# ---------------------------------------------------------------------------
-# decode attention
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("B,S,H,K,d,length", [
-    (2, 1024, 8, 2, 64, 700),
-    (1, 2048, 4, 4, 128, 2048),
-    (3, 512, 6, 2, 64, 1),
-    (2, 512, 8, 8, 64, 311),
+@needs_pallas_interpret
+@pytest.mark.parametrize("N,M", [
+    (16, 128),    # exactly one tile
+    (9, 70),      # one tile, ragged on both sides
+    (100, 300),   # ragged row and column blocks
+    (48, 384),    # tiles divide both sides
 ])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_decode_attention_sweep(B, S, H, K, d, length, dtype):
-    q = jnp.asarray(RNG.normal(size=(B, H, d)), dtype)
-    kc = jnp.asarray(RNG.normal(size=(B, S, K, d)), dtype)
-    vc = jnp.asarray(RNG.normal(size=(B, S, K, d)), dtype)
-    o = decode_attention(q, kc, vc, jnp.int32(length), impl="interpret")
-    r = decode_attention(q, kc, vc, jnp.int32(length), impl="ref")
-    tol = 1e-5 if dtype == jnp.float32 else 3e-2
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(r, np.float32), atol=tol)
+def test_pair_scores_compact_at_blocked_cell_tile(N, M):
+    """``pair_scores_compact`` with ``wa.blocked``'s 16 x 128 tile at
+    D = 300: the dense oracle's candidates, in tile-list order, within
+    ``BAND`` — one-tile and ragged lists included."""
+    rng = np.random.default_rng(N + M)
+    cents = rng.normal(size=(24, D_CELLS))
+    a = l2_normalize(jnp.asarray(_clustered(rng, N, cents), jnp.float32))
+    b = l2_normalize(jnp.asarray(_clustered(rng, M, cents), jnp.float32))
+    tau = 0.5
+    rows, cols, scores, n_tot = _compact_dense(a, b, tau, N * M, 16, 128)
+    rr, rc, rs = candidates_ref(a, b, tau)
+    assert n_tot == len(rows) and len(rr) > 0
+    dmax, extra, missing = candidate_diff((rows, cols, scores),
+                                          (rr, rc, rs))
+    assert dmax <= BAND
+    assert (extra < tau + BAND).all() and (missing < tau + BAND).all()
+    order = np.lexsort((cols, rows, cols // 128, rows // 16))
+    np.testing.assert_array_equal(order, np.arange(len(rows)))
 
 
-def test_decode_attention_ignores_tail_garbage():
-    """Entries past `length` must not affect the result."""
-    B, S, H, K, d = 1, 512, 4, 2, 64
-    q = jnp.asarray(RNG.normal(size=(B, H, d)), jnp.float32)
-    kc = jnp.asarray(RNG.normal(size=(B, S, K, d)), jnp.float32)
-    vc = jnp.asarray(RNG.normal(size=(B, S, K, d)), jnp.float32)
-    o1 = decode_attention(q, kc, vc, jnp.int32(100), impl="interpret")
-    kc2 = kc.at[:, 100:].set(1e9)
-    vc2 = vc.at[:, 100:].set(-1e9)
-    o2 = decode_attention(q, kc2, vc2, jnp.int32(100), impl="interpret")
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-6)
+@pytest.mark.parametrize("variant", ["one_piece", "row_chunked", "overflow"])
+def test_sharded_candidates_at_cell_width(monkeypatch, variant):
+    """``wa.dense``'s program on a 1x1 mesh at D = 300: the oracle's
+    candidates in row-major order, whether the block is compacted in one
+    piece or seven rows at a time; under a capacity of half of them, the
+    first half in the same order and the rest counted as dropped."""
+    from repro.kernels.pair_scores import sharded
+    from repro.launch.mesh import make_host_mesh
+
+    rng = np.random.default_rng(300)
+    cents = rng.normal(size=(40, D_CELLS))
+    n = 301 if variant == "row_chunked" else 300   # a shape of its own
+    a = jnp.asarray(_clustered(rng, n, cents), jnp.float32)
+    b = jnp.asarray(_clustered(rng, 500, cents), jnp.float32)
+    mesh = make_host_mesh(1, 1)
+    if variant == "row_chunked":
+        monkeypatch.setattr(sharded, "CHUNK_CELLS", 7 * 500)
+        assert sharded._chunk_rows(n, 500) == 7
+    full = sharded.sharded_candidates(a, b, 0.5, mesh)
+    ref = candidates_ref(l2_normalize(a), l2_normalize(b), 0.5)
+    assert full.n_dropped == 0 and len(full) > 500
+    dmax, extra, missing = candidate_diff(
+        (full.rows, full.cols, full.scores), ref)
+    assert dmax <= BAND
+    assert (extra < 0.5 + BAND).all() and (missing < 0.5 + BAND).all()
+    np.testing.assert_array_equal(np.lexsort((full.cols, full.rows)),
+                                  np.arange(len(full)))
+    if variant == "overflow":
+        cap = len(full) // 2
+        half = sharded.sharded_candidates(a, b, 0.5, mesh, capacity=cap)
+        np.testing.assert_array_equal(half.rows, full.rows[:cap])
+        np.testing.assert_array_equal(half.cols, full.cols[:cap])
+        np.testing.assert_array_equal(half.scores, full.scores[:cap])
+        assert half.n_dropped == len(full) - cap
+        assert half.suggested_capacity >= len(full)

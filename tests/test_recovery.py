@@ -252,9 +252,9 @@ def test_checkpoint_every_validates():
 
 
 def test_restore_without_sidecar_rejected(tmp_path):
-    """A checkpoint written by the train path (no serving sidecar) is not
-    silently misinterpreted as serving state."""
-    from repro.train.checkpoint import CheckpointManager
+    """A checkpoint without a serving sidecar is not silently
+    misinterpreted as serving state."""
+    from repro.serve.checkpoint import CheckpointManager
     CheckpointManager(tmp_path).save(0, {"x": np.ones(3)})
     with pytest.raises(FileNotFoundError, match="sidecar"):
         JoinService.restore(str(tmp_path))

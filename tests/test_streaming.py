@@ -130,7 +130,8 @@ def _noisy_stream_parity(world_builder, seed: int, flip: float = 0.35):
     # stage 1: noisy answers over a random half of epoch 1, on both states
     take1 = rng.permutation(p1)[:max(p1 // 2, 1)]
     ans1 = noisy(take1)
-    upd_small = np.full(8, UNKNOWN, np.int32)
+    # an epoch of more than 8 pairs gets a state that large
+    upd_small = np.full(int(state.u.shape[0]), UNKNOWN, np.int32)
     upd_small[take1] = ans1
     upd_ref = np.full(p_cap, UNKNOWN, np.int32)
     upd_ref[take1] = ans1
